@@ -160,7 +160,7 @@ def time_kernel_train_step(args) -> None:
 
     ``--autotune`` enables the tile autotuner (``kernels/tuning.py``): cache
     misses are measured with timed kernel runs and persisted to the JSON
-    cache ($REPRO_TUNING_CACHE, default ~/.cache/repro/tuning.json); a
+    cache ($REPRO_TUNING_CACHE; unset keeps it in memory only); a
     second run hits the cache and re-measures nothing.  ``--bench-json``
     writes the measured record; ``--baseline BENCH_perf_iter.json`` compares
     against a committed record and exits non-zero if throughput regressed
@@ -740,7 +740,7 @@ def main():
                     help="enable the tile autotuner (kernels/tuning.py): "
                          "measure candidate (tq, tk) grids on cache miss and "
                          "persist to $REPRO_TUNING_CACHE "
-                         "(~/.cache/repro/tuning.json); second run hits cache")
+                         "(unset: in memory only); second run hits cache")
     ap.add_argument("--bench-json", default=None,
                     help="kernel-step: write the measured record "
                          "(points/sec, peak bytes) to this JSON file")

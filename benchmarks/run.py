@@ -6,7 +6,6 @@ Prints ``name,us_per_call,derived`` CSV lines.
 """
 
 import argparse
-import os
 import sys
 import traceback
 from pathlib import Path
@@ -20,9 +19,9 @@ if __package__ in (None, ""):                    # `python benchmarks/run.py`
 
 def smoke() -> None:
     """One tiny fwd+bwd iteration through BOTH attention stacks on the Pallas
-    kernel path (interpret mode on CPU) — proves the custom-VJP kernels stay
-    jit-compatible end-to-end.  Exits non-zero on NaN/Inf."""
-    os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
+    kernel path (interpret mode on CPU, compiled on TPU) — proves the
+    custom-VJP kernels stay jit-compatible end-to-end.  Exits non-zero on
+    NaN/Inf."""
     import time
 
     import jax
@@ -30,6 +29,7 @@ def smoke() -> None:
 
     from repro.core import (BSAConfig, bsa_attention, bsa_init,
                             nsa_causal_attention, nsa_init)
+    from repro.kernels.common import should_interpret
 
     B, N, Hq, Hkv, D, dm = 1, 128, 4, 2, 32, 64
     cfg = BSAConfig(ball_size=32, local_window=32, cmp_block=8, slc_block=8,
@@ -60,7 +60,9 @@ def smoke() -> None:
     if not ok:
         print("FAILURES: smoke (non-finite loss/grads)")
         sys.exit(1)
-    print("# smoke complete (kernel path fwd+bwd, interpret mode)")
+    mode = "interpret" if should_interpret() else "compiled"
+    print(f"# smoke complete (kernel path fwd+bwd, {mode} mode, "
+          f"{jax.devices()[0].platform})")
 
 
 def main() -> None:

@@ -20,6 +20,7 @@ from repro.configs import get_config
 from repro.data import ShapeNetCarDataset
 from repro.models.api import model_api
 from repro.runtime import Trainer, TrainerConfig
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def evaluate(api, params, ds, n_batches=8, batch_size=8, pad_to=None):
@@ -57,6 +58,7 @@ def main():
                     help="ragged geometries: per-sample point counts drawn from "
                          "[LO, HI]; batches are packed + masked (batched path)")
     args = ap.parse_args()
+    use_compile_cache()
 
     mcfg = get_config(args.arch)
     if args.layers:

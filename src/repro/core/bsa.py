@@ -205,9 +205,40 @@ def _selection_scores(params, q, k_cmp, blk_valid, mask, cfg: BSAConfig,
     return s
 
 
+def _select_blocks(scores, k_star: int, select=None):
+    """Top-k block ids of every (group, KV head) row and their validity.
+
+    ``select`` (the ids' shape, −1 = an invalid selection) REPLAYS a
+    selection made elsewhere — by another layout or backend, whose rounding
+    may break a near-tie the other way: its rows are used instead of this
+    call's top-k, except rows whose first id is −1, which keep their own.
+    Returns (ids, valid, stats); ``stats`` is empty without ``select``,
+    else ``gap``: the largest (own k-th score − lowest replayed score) /
+    (1 + |own k-th score|) over replayed rows with k valid candidates —
+    ≤ 0 when every replayed set is a top-k of THESE scores, rounding-sized
+    at a near-tie, large for a wrong or invalid block — and ``flips``: how
+    many of those rows differ, as a set, from this call's own top-k.
+    """
+    top_vals, top_idx = jax.lax.top_k(scores, k_star)
+    if select is None:
+        return top_idx, top_vals > NEG_INF / 2, {}
+    replay = select[..., :1] >= 0
+    idx = jnp.where(replay, jnp.maximum(select, 0), top_idx)
+    vals = jnp.where(replay & (select < 0), NEG_INF,
+                     jnp.take_along_axis(scores, idx, axis=-1))
+    kth = top_vals[..., -1]
+    live = replay[..., 0] & (kth > NEG_INF / 2)
+    gap = jnp.max(jnp.where(live, (kth - vals.min(-1)) / (1 + jnp.abs(kth)),
+                            -jnp.inf))
+    differs = jnp.any(jnp.sort(idx, -1) != jnp.sort(top_idx, -1), axis=-1)
+    return idx, vals > NEG_INF / 2, {"gap": gap,
+                                     "flips": jnp.sum(live & differs)}
+
+
 def _selection_branch(params, q, k, v, k_cmp, blk_valid, mask, cfg: BSAConfig,
-                      backend):
-    """Top-k block gather + exact attention.  Returns (out, indices)."""
+                      backend, select=None):
+    """Top-k block gather + exact attention.  Returns (out, indices, stats)
+    (``stats``: see :func:`_select_blocks`)."""
     B, N, Hq, D = q.shape
     Hkv = k.shape[2]
     rep = Hq // Hkv
@@ -217,14 +248,13 @@ def _selection_branch(params, q, k, v, k_cmp, blk_valid, mask, cfg: BSAConfig,
     scores = _selection_scores(params, q, k_cmp, blk_valid, mask, cfg)  # (B,G,Hkv,NB)
     G = scores.shape[1]
     g = N // G
-    k_star = min(cfg.top_k, nb)
-    top_vals, top_idx = jax.lax.top_k(scores, k_star)              # (B,G,Hkv,k*)
-    sel_valid = top_vals > NEG_INF / 2                              # (B,G,Hkv,k*)
+    top_idx, sel_valid, stats = _select_blocks(
+        scores, min(cfg.top_k, nb), select)                        # (B,G,Hkv,k*)
 
     out = backend.selection(q, k, v, top_idx, sel_valid, mask,
                             block_size=ell, group_size=g,
                             chunk_tokens=cfg.jnp_chunk_tokens)
-    return out, top_idx
+    return out, jnp.where(sel_valid, top_idx, -1), stats
 
 
 # ---------------------------------------------------------------------------
@@ -233,7 +263,8 @@ def _selection_branch(params, q, k, v, k_cmp, blk_valid, mask, cfg: BSAConfig,
 
 def bsa_attention(params: dict, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                   *, cfg: BSAConfig, mask: jnp.ndarray | None = None,
-                  x: jnp.ndarray | None = None, return_aux: bool = False):
+                  x: jnp.ndarray | None = None, return_aux: bool = False,
+                  select: jnp.ndarray | None = None):
     """Ball Sparse Attention (paper Eq. 9).
 
     q: (B, N, Hq, D); k, v: (B, N, Hkv, D); mask: (B, N) bool (True = real).
@@ -242,7 +273,10 @@ def bsa_attention(params: dict, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     equals running every cloud alone — padded keys are masked in logit space
     on every branch (kernels included), padded query rows are zeroed here.
     ``x`` is the pre-projection layer input, needed only for token gating.
-    Returns (B, N, Hq, D) [+ aux dict].
+    ``select``: (B, G, Hkv, k*) block ids to replay instead of this call's
+    top-k (see :func:`_select_blocks`; its ``gap``/``flips`` join aux).
+    Returns (B, N, Hq, D) [+ aux dict; its ``indices`` are the selected
+    block ids, −1 where a selection is invalid].
     """
     B, N, Hq, D = q.shape
     assert k.shape[:2] == (B, N) and v.shape == k.shape
@@ -266,8 +300,8 @@ def bsa_attention(params: dict, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     out_ball = _ball_branch(q, k, v, mask, cfg, bk["ball"])
     out_cmp, k_cmp, v_cmp, blk_valid = _compression_branch(
         params, q, k, v, mask, cfg, bk["cmp"])
-    out_slc, top_idx = _selection_branch(
-        params, q, k, v, k_cmp, blk_valid, mask, cfg, bk["slc"])
+    out_slc, top_idx, stats = _selection_branch(
+        params, q, k, v, k_cmp, blk_valid, mask, cfg, bk["slc"], select)
 
     gates = gate_values(params["gates"], cfg, x, Hq)
     # fused epilogue: gate + sum + query-mask in one pass (the pallas
@@ -278,7 +312,7 @@ def bsa_attention(params: dict, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     out = constrain(out, "batch", "seq_sp", None, None)
     if return_aux:
         return out, {"ball": out_ball, "cmp": out_cmp, "slc": out_slc,
-                     "indices": top_idx, "gates": gates}
+                     "indices": top_idx, "gates": gates, **stats}
     return out
 
 
@@ -287,7 +321,8 @@ def bsa_attention_varlen(params: dict, q: jnp.ndarray, k: jnp.ndarray,
                          offsets: jnp.ndarray,
                          mask: jnp.ndarray | None = None,
                          x: jnp.ndarray | None = None,
-                         return_aux: bool = False):
+                         return_aux: bool = False,
+                         select: jnp.ndarray | None = None):
     """Ball Sparse Attention over a PACKED-VARLEN batch (``docs/varlen.md``).
 
     q: (T, Hq, D); k, v: (T, Hkv, D) — all samples concatenated on one
@@ -305,7 +340,8 @@ def bsa_attention_varlen(params: dict, q: jnp.ndarray, k: jnp.ndarray,
     selects blocks of its own segment), compression and local windows via
     in-kernel segment-id masking — but no padding FLOPs are spent on dummy
     batch slots.  ``x`` is the pre-projection input for token gating, shape
-    (T, d_model).  Returns (T, Hq, D) [+ aux dict].
+    (T, d_model).  ``select``: (G, Hkv, k*) packed-axis block ids to replay
+    (see :func:`bsa_attention`).  Returns (T, Hq, D) [+ aux dict].
     """
     T, Hq, D = q.shape
     assert k.shape[0] == T and v.shape == k.shape
@@ -347,9 +383,8 @@ def bsa_attention_varlen(params: dict, q: jnp.ndarray, k: jnp.ndarray,
     scores = _selection_scores(params, q[None], k_cmp[None], blk_valid,
                                maskb, cfg, q_seg=seg)              # (1,G,Hkv,NB)
     G = scores.shape[1]
-    k_star = min(cfg.top_k, nb)
-    top_vals, top_idx = jax.lax.top_k(scores, k_star)
-    sel_valid = top_vals > NEG_INF / 2
+    top_idx, sel_valid, stats = _select_blocks(
+        scores, min(cfg.top_k, nb), None if select is None else select[None])
     out_slc = get_varlen(bk["slc"], "selection")(
         q, k, v, top_idx[0], sel_valid[0], offsets, mask,
         block_size=ell, group_size=T // G, chunk_tokens=ct)
@@ -361,5 +396,6 @@ def bsa_attention_varlen(params: dict, q: jnp.ndarray, k: jnp.ndarray,
         (gates["ball"], gates["cmp"], gates["slc"]), maskb)[0].astype(in_dtype)
     if return_aux:
         return out, {"ball": out_ball, "cmp": out_cmp, "slc": out_slc,
-                     "indices": top_idx[0], "gates": gates}
+                     "indices": jnp.where(sel_valid, top_idx, -1)[0],
+                     "gates": gates, **stats}
     return out

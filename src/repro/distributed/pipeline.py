@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -65,5 +65,5 @@ def pipeline_apply(stage_fn, stage_params, x_micro, *, mesh, axis_name="stage"):
 
     fn = shard_map(per_stage, mesh=mesh,
                    in_specs=(P(axis_name), P()),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return fn(stage_params, x_micro)

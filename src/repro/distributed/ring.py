@@ -355,7 +355,10 @@ def ring_selection(q, k, v, top_idx, sel_valid, key_valid, q_valid, *,
                                      (m, l, acc))
             if h < p - 1:
                 kc, vc, mc = _rotate((kc, vc, mc), axis, p)
-        out = acc / jnp.maximum(l, _TINY)[..., None]
+        # a group with no valid selection has l = acc = 0 and returns 0;
+        # dividing by 1 there keeps the backward finite (acc/max(l, tiny)
+        # differentiates through tiny**-2, which overflows to inf)
+        out = acc / jnp.where(l > 0, l, 1.0)[..., None]
         out = out.transpose(0, 2, 4, 1, 3, 5).reshape(B, n, Hq, D)
         return out.astype(v.dtype)
 

@@ -2,7 +2,7 @@
 
 The ``"sharded"`` backend wraps any inner single-device backend
 (``"jnp"`` / ``"pallas"`` / ``"interpret"``) and runs the four GQA-native
-ops of the backend protocol under :func:`jax.experimental.shard_map` on the
+ops of the backend protocol under :func:`jax.shard_map` on the
 mesh activated by :func:`mesh_context` — the distributed analogue of
 ``use_backend()``:
 
@@ -74,7 +74,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.backend import (
@@ -174,7 +174,7 @@ def _shard_call(mesh, body, arg_specs, out_specs):
         return body(*[next(it) if pr else None for pr in present])
 
     return shard_map(wrapper, mesh=mesh, in_specs=specs,
-                     out_specs=out_specs, check_rep=False)(*args)
+                     out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -735,7 +735,7 @@ def sharded_paged_decode(backend, params, q1, k1, v1, cache, table,
         return body(*[next(it) if pr else None for pr in present])
 
     return shard_map(wrapper, mesh=mesh, in_specs=specs,
-                     out_specs=(P(), pool_spec), check_rep=False)(*arrs)
+                     out_specs=(P(), pool_spec), check_vma=False)(*arrs)
 
 
 if "sharded" not in list_backends():       # idempotent on re-import paths

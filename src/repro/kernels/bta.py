@@ -48,7 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (LSE_EMPTY, NEG_INF, interpret_batch_map,
                                   lse_finalize, mma_dtype, p_from_lse,
-                                  resolve_compute_dtype, should_interpret)
+                                  resolve_compute_dtype, rows_to_column,
+                                  should_interpret)
 from repro.kernels.occupancy import key_tile_live
 
 __all__ = ["ball_attention_kernel_call"]
@@ -105,14 +106,14 @@ def _bwd_kernel(live_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = s + bias_ref[0]
-        p = p_from_lse(s, lse_ref[0].reshape(rep * m, 1))  # (rep·m, m)
+        p = p_from_lse(s, rows_to_column(lse_ref[0]))  # (rep·m, m)
         # dK/dV: one matmul sums over the rep·m group rows — the GQA group's
         # gradient accumulation is the contraction itself
         dv = jax.lax.dot_general(p.astype(adt), do, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0].reshape(rep * m, 1)) * scale
+        ds = p * (dp - rows_to_column(delta_ref[0])) * scale
         dq = jax.lax.dot_general(ds.astype(adt), k.astype(adt),
                                  (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -139,7 +140,7 @@ def _fwd_call(q, k, v, key_bias, ball_live, *, ball_size, n_heads, interpret,
     H = n_heads                                           # KV heads
     qblk = pl.BlockSpec((1, rep, m, D), lambda b, i, lv: (b, 0, i, 0))
     kvblk = pl.BlockSpec((1, m, D), lambda b, i, lv: (b, i, 0))
-    bias_blk = pl.BlockSpec((1, m), lambda b, i, lv: (b // H, i))
+    bias_blk = pl.BlockSpec((1, 1, m), lambda b, i, lv: (b // H, 0, i))
     lse_blk = pl.BlockSpec((1, rep, m), lambda b, i, lv: (b, 0, i))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -153,8 +154,9 @@ def _fwd_call(q, k, v, key_bias, ball_live, *, ball_size, n_heads, interpret,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((BH, rep, N, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, rep, N), jnp.float32)),
+        name="bsa_ball_fwd",
         interpret=interpret,
-    )(ball_live, q, k, v, key_bias)
+    )(ball_live, q, k, v, key_bias[:, None])
 
 
 def _bwd_call(q, k, v, key_bias, ball_live, do, lse, delta, *, ball_size,
@@ -164,7 +166,7 @@ def _bwd_call(q, k, v, key_bias, ball_live, do, lse, delta, *, ball_size,
     H = n_heads
     qblk = pl.BlockSpec((1, rep, m, D), lambda b, i, lv: (b, 0, i, 0))
     kvblk = pl.BlockSpec((1, m, D), lambda b, i, lv: (b, i, 0))
-    bias_blk = pl.BlockSpec((1, m), lambda b, i, lv: (b // H, i))
+    bias_blk = pl.BlockSpec((1, 1, m), lambda b, i, lv: (b // H, 0, i))
     row_blk = pl.BlockSpec((1, rep, m), lambda b, i, lv: (b, 0, i))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -179,8 +181,9 @@ def _bwd_call(q, k, v, key_bias, ball_live, do, lse, delta, *, ball_size,
         out_shape=(jax.ShapeDtypeStruct((BH, rep, N, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, N, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, N, D), v.dtype)),
+        name="bsa_ball_bwd",
         interpret=interpret,
-    )(ball_live, q, k, v, key_bias, do, lse, delta)
+    )(ball_live, q, k, v, key_bias[:, None], do, lse, delta)
 
 
 @functools.lru_cache(maxsize=None)

@@ -88,6 +88,17 @@ def p_from_lse(s, lse):
     return jnp.where(s <= NEG_INF / 2, 0.0, p)
 
 
+def rows_to_column(x):
+    """(rep, t) per-query-row statistics → the (rep·t, 1) column of the
+    fused rep-major row tile (row r·t + i ↔ x[r, i]).
+
+    Mosaic relayouts one lane-major row into a sublane column, but not a
+    whole (rep, t) block in one reshape, so rep > 1 goes row by row."""
+    rep, t = x.shape
+    return jnp.concatenate([x[r:r + 1].reshape(t, 1) for r in range(rep)],
+                           axis=0)
+
+
 def interpret_batch_map(fn, *args):
     """Sequential ``lax.map`` of a kernel call over leading-dim slices.
 

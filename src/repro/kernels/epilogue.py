@@ -67,6 +67,7 @@ def _fwd_call(o1, o2, o3, g1, g2, g3, m, *, tile, interpret):
         in_specs=[row, row, row, col, col, col, col],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((R, D), o1.dtype),
+        name="bsa_epilogue_fwd",
         interpret=interpret,
     )(o1, o2, o3, g1, g2, g3, m)
 
@@ -85,6 +86,7 @@ def _bwd_call(o1, o2, o3, g1, g2, g3, m, do, *, tile, interpret):
                    jax.ShapeDtypeStruct((R, 1), jnp.float32),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)),
+        name="bsa_epilogue_bwd",
         interpret=interpret,
     )(o1, o2, o3, g1, g2, g3, m, do)
 

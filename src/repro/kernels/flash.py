@@ -62,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (NEG_INF, interpret_batch_map, lse_finalize,
                                   mma_dtype, p_from_lse, resolve_compute_dtype,
-                                  should_interpret)
+                                  rows_to_column, should_interpret)
 
 __all__ = ["flash_attention_kernel_call"]
 
@@ -110,7 +110,7 @@ def _fwd_kernel(live_ref, q_ref, k_ref, v_ref, kbias_ref, o_ref, lse_ref,
         v = v_ref[0].astype(adt)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        s = s + kbias_ref[0]                               # (Tk,) key-validity bias
+        s = s + kbias_ref[0]                               # (1, Tk) key-validity bias
         s = _mask_logits(s, i, j, rows=rows, tq=tq, tk=tk, causal=causal,
                          block_causal=block_causal, ell=ell)
 
@@ -167,10 +167,10 @@ def _dq_kernel(live_ref, q_ref, k_ref, v_ref, kbias_ref, do_ref, lse_ref,
         s = s + kbias_ref[0]
         s = _mask_logits(s, i, j, rows=rows, tq=tq, tk=tk, causal=causal,
                          block_causal=block_causal, ell=ell)
-        p = p_from_lse(s, lse_ref[0].reshape(rows, 1))     # (rep·Tq, Tk)
+        p = p_from_lse(s, rows_to_column(lse_ref[0]))     # (rep·Tq, Tk)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0].reshape(rows, 1)) * scale
+        ds = p * (dp - rows_to_column(delta_ref[0])) * scale
         dq_scr[...] += jax.lax.dot_general(ds.astype(adt), ka,
                                            (((1,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
@@ -210,7 +210,7 @@ def _dkv_kernel(live_ref, q_ref, k_ref, v_ref, kbias_ref, do_ref, lse_ref,
         s = s + kbias_ref[0]
         s = _mask_logits(s, i, j, rows=rows, tq=tq, tk=tk, causal=causal,
                          block_causal=block_causal, ell=ell)
-        p = p_from_lse(s, lse_ref[0].reshape(rows, 1))     # (rep·Tq, Tk)
+        p = p_from_lse(s, rows_to_column(lse_ref[0]))     # (rep·Tq, Tk)
         # the (0,)-axis contraction sums over rep·Tq rows: the GQA group's
         # dK/dV accumulation happens inside the matmul
         dv_scr[...] += jax.lax.dot_general(p.astype(adt), do,
@@ -218,7 +218,7 @@ def _dkv_kernel(live_ref, q_ref, k_ref, v_ref, kbias_ref, do_ref, lse_ref,
                                            preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0].reshape(rows, 1)) * scale
+        ds = p * (dp - rows_to_column(delta_ref[0])) * scale
         dk_scr[...] += jax.lax.dot_general(ds.astype(adt), qa,
                                            (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
@@ -245,7 +245,7 @@ def _fwd_call(q, k, v, key_bias, live, *, n_heads, tq, tk, causal,
             pl.BlockSpec((1, rep, tq, D), lambda b, i, j, lv: (b, 0, i, 0)),
             pl.BlockSpec((1, tk, D), lambda b, i, j, lv: (b, j, 0)),
             pl.BlockSpec((1, tk, D), lambda b, i, j, lv: (b, j, 0)),
-            pl.BlockSpec((1, tk), lambda b, i, j, lv: (b // n_heads, j)),
+            pl.BlockSpec((1, 1, tk), lambda b, i, j, lv: (b // n_heads, 0, j)),
         ],
         out_specs=(pl.BlockSpec((1, rep, tq, D), lambda b, i, j, lv: (b, 0, i, 0)),
                    pl.BlockSpec((1, rep, tq), lambda b, i, j, lv: (b, 0, i))),
@@ -260,8 +260,9 @@ def _fwd_call(q, k, v, key_bias, live, *, n_heads, tq, tk, causal,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((BH, rep, N, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, rep, N), jnp.float32)),
+        name="bsa_flash_fwd",
         interpret=interpret,
-    )(live, q, k, v, key_bias)
+    )(live, q, k, v, key_bias[:, None])
 
 
 def _bwd_calls(q, k, v, key_bias, live, do, lse, delta, *, n_heads, tq, tk,
@@ -280,7 +281,7 @@ def _bwd_calls(q, k, v, key_bias, live, do, lse, delta, *, n_heads, tq, tk,
             pl.BlockSpec((1, rep, tq, D), lambda b, i, j, lv: (b, 0, i, 0)),
             pl.BlockSpec((1, tk, D), lambda b, i, j, lv: (b, j, 0)),
             pl.BlockSpec((1, tk, D), lambda b, i, j, lv: (b, j, 0)),
-            pl.BlockSpec((1, tk), lambda b, i, j, lv: (b // H, j)),
+            pl.BlockSpec((1, 1, tk), lambda b, i, j, lv: (b // H, 0, j)),
             pl.BlockSpec((1, rep, tq, D), lambda b, i, j, lv: (b, 0, i, 0)),
             pl.BlockSpec((1, rep, tq), lambda b, i, j, lv: (b, 0, i)),
             pl.BlockSpec((1, rep, tq), lambda b, i, j, lv: (b, 0, i)),
@@ -293,8 +294,9 @@ def _bwd_calls(q, k, v, key_bias, live, do, lse, delta, *, n_heads, tq, tk,
         functools.partial(_dq_kernel, n_k=n_k, **mask_kw),
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((BH, rep, N, D), q.dtype),
+        name="bsa_flash_dq",
         interpret=interpret,
-    )(live, q, k, v, key_bias, do, lse, delta)
+    )(live, q, k, v, key_bias[:, None], do, lse, delta)
 
     dkv_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -303,7 +305,7 @@ def _bwd_calls(q, k, v, key_bias, live, do, lse, delta, *, n_heads, tq, tk,
             pl.BlockSpec((1, rep, tq, D), lambda b, j, i, lv: (b, 0, i, 0)),
             pl.BlockSpec((1, tk, D), lambda b, j, i, lv: (b, j, 0)),
             pl.BlockSpec((1, tk, D), lambda b, j, i, lv: (b, j, 0)),
-            pl.BlockSpec((1, tk), lambda b, j, i, lv: (b // H, j)),
+            pl.BlockSpec((1, 1, tk), lambda b, j, i, lv: (b // H, 0, j)),
             pl.BlockSpec((1, rep, tq, D), lambda b, j, i, lv: (b, 0, i, 0)),
             pl.BlockSpec((1, rep, tq), lambda b, j, i, lv: (b, 0, i)),
             pl.BlockSpec((1, rep, tq), lambda b, j, i, lv: (b, 0, i)),
@@ -318,8 +320,9 @@ def _bwd_calls(q, k, v, key_bias, live, do, lse, delta, *, n_heads, tq, tk,
         grid_spec=dkv_spec,
         out_shape=(jax.ShapeDtypeStruct((BH, L, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, L, D), v.dtype)),
+        name="bsa_flash_dkv",
         interpret=interpret,
-    )(live, q, k, v, key_bias, do, lse, delta)
+    )(live, q, k, v, key_bias[:, None], do, lse, delta)
     return dq, dk, dv
 
 

@@ -18,7 +18,9 @@ batch of mixed-size sequences is one grid launch.
 
 TILE-OCCUPANCY SKIPPING at HALF-TILE granularity (``kernels/occupancy.py``):
 per-block any-valid-key verdicts (B, n_b) int32 ride in as a SCALAR-PREFETCH
-operand.  The forward streams the prev half and the self half as two
+operand, beside the per-block segment ids of packed-varlen batches (also
+(B, n_b) int32: read as scalars, a (1, 1) VMEM block would not lower).  The
+forward streams the prev half and the self half as two
 separately ``pl.when``-guarded softmax steps over shared m/l/acc scratch —
 a block whose prev neighbour is all-masked (or absent: block 0 / a packed
 sample boundary) computes only the self half; a block whose own keys are
@@ -54,7 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (NEG_INF, interpret_batch_map, lse_finalize,
                                   mma_dtype, p_from_lse, resolve_compute_dtype,
-                                  should_interpret)
+                                  rows_to_column, should_interpret)
 from repro.kernels.occupancy import key_tile_live
 
 __all__ = ["local_window_kernel_call"]
@@ -69,8 +71,8 @@ def _causal_mask(s, *, rows, w):
     return jnp.where(ki <= qi, s, NEG_INF)
 
 
-def _fwd_kernel(kvl_ref, q_ref, ks_ref, vs_ref, kp_ref, vp_ref, bs_ref, bp_ref,
-                ss_ref, sp_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
+def _fwd_kernel(kvl_ref, seg_ref, q_ref, ks_ref, vs_ref, kp_ref, vp_ref,
+                bs_ref, bp_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                 scale: float, w: int, nh: int, compute: str):
     b = pl.program_id(0)
     i = pl.program_id(1)
@@ -80,7 +82,7 @@ def _fwd_kernel(kvl_ref, q_ref, ks_ref, vs_ref, kp_ref, vp_ref, bs_ref, bp_ref,
     adt = jnp.dtype(mma_dtype(compute))
     sb = b // nh
     live_self = kvl_ref[sb, i] != 0
-    live_prev = ((i > 0) & (sp_ref[0, 0] == ss_ref[0, 0])
+    live_prev = ((i > 0) & (seg_ref[sb, jnp.maximum(i - 1, 0)] == seg_ref[sb, i])
                  & (kvl_ref[sb, jnp.maximum(i - 1, 0)] != 0))
 
     # one visit per grid cell — init unconditionally, halves merge into it
@@ -123,9 +125,9 @@ def _fwd_kernel(kvl_ref, q_ref, ks_ref, vs_ref, kp_ref, vp_ref, bs_ref, bp_ref,
     lse_ref[0] = lse_finalize(m_safe_f, l_scr[...])[:, 0].reshape(rep, w)
 
 
-def _bwd_kernel(kvl_ref, qs_ref, qn_ref, ks_ref, kp_ref, vs_ref, vp_ref,
-                bs_ref, bp_ref, ss_ref, sp_ref, sn_ref,
-                dos_ref, don_ref, lses_ref, lsen_ref, dels_ref, deln_ref,
+def _bwd_kernel(kvl_ref, seg_ref, qs_ref, qn_ref, ks_ref, kp_ref, vs_ref,
+                vp_ref, bs_ref, bp_ref, dos_ref, don_ref, lses_ref, lsen_ref,
+                dels_ref, deln_ref,
                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
                 scale: float, w: int, n_b: int, nh: int, compute: str):
     b = pl.program_id(0)
@@ -136,11 +138,13 @@ def _bwd_kernel(kvl_ref, qs_ref, qn_ref, ks_ref, kp_ref, vs_ref, vp_ref,
     adt = jnp.dtype(mma_dtype(compute))
     sb = b // nh
     live_self = kvl_ref[sb, i] != 0                        # my keys carry weight
-    live_prev = ((i > 0) & (sp_ref[0, 0] == ss_ref[0, 0])
+    seg = seg_ref[sb, i]
+    live_prev = ((i > 0) & (seg_ref[sb, jnp.maximum(i - 1, 0)] == seg)
                  & (kvl_ref[sb, jnp.maximum(i - 1, 0)] != 0))
     # next block's queries contribute to MY dK/dV iff my keys are valid and a
     # real same-sample next block exists
-    live_next = (i < n_b - 1) & (sn_ref[0, 0] == ss_ref[0, 0]) & live_self
+    live_next = ((i < n_b - 1)
+                 & (seg_ref[sb, jnp.minimum(i + 1, n_b - 1)] == seg) & live_self)
 
     dq_scr[...] = jnp.zeros_like(dq_scr)
     dk_scr[...] = jnp.zeros_like(dk_scr)
@@ -148,8 +152,8 @@ def _bwd_kernel(kvl_ref, qs_ref, qn_ref, ks_ref, kp_ref, vs_ref, vp_ref,
 
     qs = qs_ref[0].astype(sdt).reshape(rows, D)            # (rep·w, D)
     dos = dos_ref[0].astype(adt).reshape(rows, D)
-    lses = lses_ref[0].reshape(rows, 1)
-    dels = dels_ref[0].reshape(rows, 1)
+    lses = rows_to_column(lses_ref[0])
+    dels = rows_to_column(dels_ref[0])
 
     @pl.when(live_prev)
     def _prev_half():                                      # prev keys → my dQ
@@ -199,14 +203,14 @@ def _bwd_kernel(kvl_ref, qs_ref, qn_ref, ks_ref, kp_ref, vs_ref, vp_ref,
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         sn = sn + bs_ref[0]
-        pn = p_from_lse(sn, lsen_ref[0].reshape(rows, 1))  # (rep·w, w)
+        pn = p_from_lse(sn, rows_to_column(lsen_ref[0]))  # (rep·w, w)
         dv_scr[...] += jax.lax.dot_general(pn.astype(adt), don,
                                            (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
         dpn = jax.lax.dot_general(don, vs_ref[0].astype(adt),
                                   (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        dsn = pn * (dpn - deln_ref[0].reshape(rows, 1)) * scale
+        dsn = pn * (dpn - rows_to_column(deln_ref[0])) * scale
         dk_scr[...] += jax.lax.dot_general(
             dsn.astype(adt), qn_ref[0].astype(adt).reshape(rows, D),
             (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -223,22 +227,19 @@ def _fwd_call(q, k, v, key_bias, blk_seg, kv_live, *, window, n_heads,
     H = n_heads                                            # KV heads
     assert N % w == 0
     n_b = N // w
-    q_blk = pl.BlockSpec((1, rep, w, D), lambda b, i, lv: (b, 0, i, 0))
-    self_blk = pl.BlockSpec((1, w, D), lambda b, i, lv: (b, i, 0))
+    q_blk = pl.BlockSpec((1, rep, w, D), lambda b, i, lv, sg: (b, 0, i, 0))
+    self_blk = pl.BlockSpec((1, w, D), lambda b, i, lv, sg: (b, i, 0))
     prev_blk = pl.BlockSpec((1, w, D),
-                            lambda b, i, lv: (b, jnp.maximum(i - 1, 0), 0))
-    bias_self = pl.BlockSpec((1, w), lambda b, i, lv: (b // H, i))
-    bias_prev = pl.BlockSpec((1, w),
-                             lambda b, i, lv: (b // H, jnp.maximum(i - 1, 0)))
-    seg_self = pl.BlockSpec((1, 1), lambda b, i, lv: (b // H, i))
-    seg_prev = pl.BlockSpec((1, 1),
-                            lambda b, i, lv: (b // H, jnp.maximum(i - 1, 0)))
-    lse_blk = pl.BlockSpec((1, rep, w), lambda b, i, lv: (b, 0, i))
+                            lambda b, i, lv, sg: (b, jnp.maximum(i - 1, 0), 0))
+    bias_self = pl.BlockSpec((1, 1, w), lambda b, i, lv, sg: (b // H, 0, i))
+    bias_prev = pl.BlockSpec(
+        (1, 1, w), lambda b, i, lv, sg: (b // H, 0, jnp.maximum(i - 1, 0)))
+    lse_blk = pl.BlockSpec((1, rep, w), lambda b, i, lv, sg: (b, 0, i))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,                             # liveness, block seg ids
         grid=(BH, n_b),
         in_specs=[q_blk, self_blk, self_blk, prev_blk, prev_blk,
-                  bias_self, bias_prev, seg_self, seg_prev],
+                  bias_self, bias_prev],
         out_specs=(q_blk, lse_blk),
         scratch_shapes=[
             pltpu.VMEM((rep * w, 1), jnp.float32),
@@ -246,14 +247,16 @@ def _fwd_call(q, k, v, key_bias, blk_seg, kv_live, *, window, n_heads,
             pltpu.VMEM((rep * w, D), jnp.float32),
         ],
     )
+    kb = key_bias[:, None]                                 # (B, 1, N)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=1.0 / (D ** 0.5), w=w, nh=H,
                           compute=compute),
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((BH, rep, N, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, rep, N), jnp.float32)),
+        name="bsa_local_fwd",
         interpret=interpret,
-    )(kv_live, q, k, v, k, v, key_bias, key_bias, blk_seg, blk_seg)
+    )(kv_live, blk_seg, q, k, v, k, v, kb, kb)
 
 
 def _bwd_call(q, k, v, key_bias, blk_seg, kv_live, do, lse, delta, *, window,
@@ -262,31 +265,29 @@ def _bwd_call(q, k, v, key_bias, blk_seg, kv_live, do, lse, delta, *, window,
     w = window
     H = n_heads
     n_b = N // w
-    q_self = pl.BlockSpec((1, rep, w, D), lambda b, i, lv: (b, 0, i, 0))
-    q_next = pl.BlockSpec((1, rep, w, D),
-                          lambda b, i, lv: (b, 0, jnp.minimum(i + 1, n_b - 1), 0))
-    self_blk = pl.BlockSpec((1, w, D), lambda b, i, lv: (b, i, 0))
-    prev_blk = pl.BlockSpec((1, w, D),
-                            lambda b, i, lv: (b, jnp.maximum(i - 1, 0), 0))
-    bias_self = pl.BlockSpec((1, w), lambda b, i, lv: (b // H, i))
-    bias_prev = pl.BlockSpec((1, w),
-                             lambda b, i, lv: (b // H, jnp.maximum(i - 1, 0)))
-    seg_self = pl.BlockSpec((1, 1), lambda b, i, lv: (b // H, i))
-    seg_prev = pl.BlockSpec((1, 1),
-                            lambda b, i, lv: (b // H, jnp.maximum(i - 1, 0)))
-    seg_next = pl.BlockSpec((1, 1),
-                            lambda b, i, lv: (b // H, jnp.minimum(i + 1, n_b - 1)))
-    row_self = pl.BlockSpec((1, rep, w), lambda b, i, lv: (b, 0, i))
-    row_next = pl.BlockSpec((1, rep, w),
-                            lambda b, i, lv: (b, 0, jnp.minimum(i + 1, n_b - 1)))
+
+    def nxt(i):
+        return jnp.minimum(i + 1, n_b - 1)
+
+    def prv(i):
+        return jnp.maximum(i - 1, 0)
+
+    q_self = pl.BlockSpec((1, rep, w, D), lambda b, i, lv, sg: (b, 0, i, 0))
+    q_next = pl.BlockSpec((1, rep, w, D), lambda b, i, lv, sg: (b, 0, nxt(i), 0))
+    self_blk = pl.BlockSpec((1, w, D), lambda b, i, lv, sg: (b, i, 0))
+    prev_blk = pl.BlockSpec((1, w, D), lambda b, i, lv, sg: (b, prv(i), 0))
+    bias_self = pl.BlockSpec((1, 1, w), lambda b, i, lv, sg: (b // H, 0, i))
+    bias_prev = pl.BlockSpec((1, 1, w),
+                             lambda b, i, lv, sg: (b // H, 0, prv(i)))
+    row_self = pl.BlockSpec((1, rep, w), lambda b, i, lv, sg: (b, 0, i))
+    row_next = pl.BlockSpec((1, rep, w), lambda b, i, lv, sg: (b, 0, nxt(i)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,                             # liveness, block seg ids
         grid=(BH, n_b),
         in_specs=[q_self, q_next,                # q self / next
                   self_blk, prev_blk,            # k self / prev
                   self_blk, prev_blk,            # v self / prev
                   bias_self, bias_prev,          # key bias self / prev
-                  seg_self, seg_prev, seg_next,  # block segment ids
                   q_self, q_next,                # do self / next
                   row_self, row_next,            # lse self / next
                   row_self, row_next],           # delta self / next
@@ -297,6 +298,7 @@ def _bwd_call(q, k, v, key_bias, blk_seg, kv_live, do, lse, delta, *, window,
             pltpu.VMEM((w, D), jnp.float32),
         ],
     )
+    kb = key_bias[:, None]                                 # (B, 1, N)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=1.0 / (D ** 0.5), w=w, n_b=n_b,
                           nh=H, compute=compute),
@@ -304,9 +306,10 @@ def _bwd_call(q, k, v, key_bias, blk_seg, kv_live, do, lse, delta, *, window,
         out_shape=(jax.ShapeDtypeStruct((BH, rep, N, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, N, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, N, D), v.dtype)),
+        name="bsa_local_bwd",
         interpret=interpret,
-    )(kv_live, q, q, k, k, v, v, key_bias, key_bias, blk_seg, blk_seg, blk_seg,
-      do, do, lse, lse, delta, delta)
+    )(kv_live, blk_seg, q, q, k, k, v, v, kb, kb, do, do, lse, lse, delta,
+      delta)
 
 
 @functools.lru_cache(maxsize=None)

@@ -159,7 +159,8 @@ def flash_attention(q, k, v, *, key_valid=None, causal=False,
                                    ell, interpret))
         tq = tq or atq
         tk = tk or atk
-    tq, tk = min(tq, tuning.round_up(N, 8)), min(tk, tuning.round_up(L, 8))
+    tq = tuning.clamp_tile(tq, N, interpret=interpret)
+    tk = tuning.clamp_tile(tk, L, interpret=interpret)
 
     kb = key_padding_bias(key_valid, B, L)
     if bias is not None:
@@ -330,7 +331,8 @@ def flash_attention_varlen(q, k, v, q_offsets, k_offsets, *, key_valid=None,
             variant="plain", layout="varlen", compute=compute)
         tq = tq or atq
         tk = tk or atk
-    tq, tk = min(tq, tuning.round_up(T, 8)), min(tk, tuning.round_up(L, 8))
+    tq = tuning.clamp_tile(tq, T, interpret=interpret)
+    tk = tuning.clamp_tile(tk, L, interpret=interpret)
 
     kb = key_padding_bias(key_valid[None] if key_valid is not None else None,
                           1, L)

@@ -38,5 +38,6 @@ def paged_gather_kernel_call(pool, rows, *, interpret: bool):
         _copy_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, H, D), pool.dtype),
+        name="bsa_paged_gather",
         interpret=interpret,
     )(rows, pool)

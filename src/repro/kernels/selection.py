@@ -50,10 +50,18 @@ from repro.kernels.common import (NEG_INF, interpret_batch_map, lse_finalize,
 __all__ = ["selection_attention_kernel_call"]
 
 
-def _fwd_kernel(idx_ref,                 # scalar prefetch (B, Hkv, G, k*) int32
+def _sel_id(idx_ref, b, h, g, j, dims):
+    """Selected block id of grid cell (b, h, g, j).  The (B, Hkv, G, k*)
+    index array rides in FLAT: SMEM pads a trailing dim to 128 words, so
+    the 4-D form would cost 32× its size at k* = 4 (SMEM is 1 MiB)."""
+    _, n_h, n_g, k_star = dims
+    return idx_ref[((b * n_h + h) * n_g + g) * k_star + j]
+
+
+def _fwd_kernel(idx_ref,                 # scalar prefetch (B·Hkv·G·k*,) int32
                 q_ref, k_ref, v_ref, tokbias_ref,
                 o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                scale: float, k_star: int, compute: str):
+                scale: float, dims: tuple, compute: str):
     b = pl.program_id(0)
     h = pl.program_id(1)
     g = pl.program_id(2)
@@ -67,7 +75,7 @@ def _fwd_kernel(idx_ref,                 # scalar prefetch (B, Hkv, G, k*) int32
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    valid = idx_ref[b, h, g, j] >= 0
+    valid = _sel_id(idx_ref, b, h, g, j, dims) >= 0
 
     @pl.when(valid)
     def _accumulate():
@@ -76,7 +84,7 @@ def _fwd_kernel(idx_ref,                 # scalar prefetch (B, Hkv, G, k*) int32
         v = v_ref[0, 0, 0].astype(adt)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        s = s + tokbias_ref[0]                             # (ℓ,) padding bias
+        s = s + tokbias_ref[0, 0]                          # (1, ℓ) padding bias
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.maximum(m_new, NEG_INF / 2)
@@ -90,20 +98,20 @@ def _fwd_kernel(idx_ref,                 # scalar prefetch (B, Hkv, G, k*) int32
             p.astype(adt), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == k_star - 1)
+    @pl.when(j == dims[3] - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-20)
         out = acc_scr[...] / denom
         out = jnp.where(l_scr[...] > 0.0, out, 0.0)        # all-invalid group → 0
         o_ref[0, 0, 0] = out.astype(o_ref.dtype)
         m_safe = jnp.maximum(m_scr[...], NEG_INF / 2)
-        lse_ref[0, 0, 0] = lse_finalize(m_safe, l_scr[...])[:, 0]
+        lse_ref[0, 0, 0] = lse_finalize(m_safe, l_scr[...])[:, 0][None]
 
 
 def _bwd_kernel(idx_ref,
                 q_ref, k_ref, v_ref, tokbias_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dkb_ref, dvb_ref, dq_scr, *,
-                scale: float, k_star: int, compute: str):
+                scale: float, dims: tuple, compute: str):
     b = pl.program_id(0)
     h = pl.program_id(1)
     g = pl.program_id(2)
@@ -118,7 +126,7 @@ def _bwd_kernel(idx_ref,
     # Invalid selections fetched a clamped (harmless) block; their grid cell
     # skips all five matmuls and writes its dkb/dvb staging tiles as exact
     # zeros — p ≡ 0 there in the oracle, so the skip is bit-exact.
-    valid = idx_ref[b, h, g, j] >= 0
+    valid = _sel_id(idx_ref, b, h, g, j, dims) >= 0
 
     @pl.when(valid)
     def _live_sel():
@@ -128,14 +136,14 @@ def _bwd_kernel(idx_ref,
         do = do_ref[0, 0, 0].astype(adt)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        s = s + tokbias_ref[0]
-        p = p_from_lse(s, lse_ref[0, 0, 0][:, None])       # (M, ℓ)
+        s = s + tokbias_ref[0, 0]
+        p = p_from_lse(s, lse_ref[0, 0, 0].reshape(-1, 1))  # (M, ℓ)
         dvb_ref[0, 0, 0, 0] = jax.lax.dot_general(
             p.astype(adt), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(dvb_ref.dtype)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0, 0][:, None]) * scale
+        ds = p * (dp - delta_ref[0, 0, 0].reshape(-1, 1)) * scale
         dkb_ref[0, 0, 0, 0] = jax.lax.dot_general(
             ds.astype(adt), q_ref[0, 0, 0].astype(adt),
             (((0,), (0,)), ((), ())),
@@ -149,7 +157,7 @@ def _bwd_kernel(idx_ref,
         dvb_ref[0, 0, 0, 0] = jnp.zeros_like(dvb_ref[0, 0, 0, 0])
         dkb_ref[0, 0, 0, 0] = jnp.zeros_like(dkb_ref[0, 0, 0, 0])
 
-    @pl.when(j == k_star - 1)
+    @pl.when(j == dims[3] - 1)
     def _finalize():
         dq_ref[0, 0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -158,18 +166,19 @@ def _fwd_call(q, kb, vb, idx, tok_bias, *, interpret, compute):
     B, Hkv, G, M, D = q.shape
     ell = kb.shape[3]
     k_star = idx.shape[-1]
+    dims = idx.shape
 
     def q_map(b, h, g, j, idx_ref):
         return (b, h, g, 0, 0)
 
     def kv_map(b, h, g, j, idx_ref):
-        return (b, h, jnp.maximum(idx_ref[b, h, g, j], 0), 0, 0)
+        return (b, h, jnp.maximum(_sel_id(idx_ref, b, h, g, j, dims), 0), 0, 0)
 
     def tok_map(b, h, g, j, idx_ref):
-        return (b, jnp.maximum(idx_ref[b, h, g, j], 0), 0)
+        return (b, jnp.maximum(_sel_id(idx_ref, b, h, g, j, dims), 0), 0, 0)
 
     def lse_map(b, h, g, j, idx_ref):
-        return (b, h, g, 0)
+        return (b, h, g, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -178,10 +187,10 @@ def _fwd_call(q, kb, vb, idx, tok_bias, *, interpret, compute):
             pl.BlockSpec((1, 1, 1, M, D), q_map),
             pl.BlockSpec((1, 1, 1, ell, D), kv_map),
             pl.BlockSpec((1, 1, 1, ell, D), kv_map),
-            pl.BlockSpec((1, 1, ell), tok_map),
+            pl.BlockSpec((1, 1, 1, ell), tok_map),
         ],
         out_specs=(pl.BlockSpec((1, 1, 1, M, D), q_map),
-                   pl.BlockSpec((1, 1, 1, M), lse_map)),
+                   pl.BlockSpec((1, 1, 1, 1, M), lse_map)),
         scratch_shapes=[
             pltpu.VMEM((M, 1), jnp.float32),
             pltpu.VMEM((M, 1), jnp.float32),
@@ -189,13 +198,14 @@ def _fwd_call(q, kb, vb, idx, tok_bias, *, interpret, compute):
         ],
     )
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=1.0 / (D ** 0.5), k_star=k_star,
+        functools.partial(_fwd_kernel, scale=1.0 / (D ** 0.5), dims=dims,
                           compute=compute),
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((B, Hkv, G, M, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, Hkv, G, M), jnp.float32)),
+                   jax.ShapeDtypeStruct((B, Hkv, G, 1, M), jnp.float32)),
+        name="bsa_selection_fwd",
         interpret=interpret,
-    )(idx, q, kb, vb, tok_bias)
+    )(idx.reshape(-1), q, kb, vb, tok_bias[:, :, None])
 
 
 def _bwd_call(q, kb, vb, idx, tok_bias, do, lse, delta, *, interpret,
@@ -203,18 +213,19 @@ def _bwd_call(q, kb, vb, idx, tok_bias, do, lse, delta, *, interpret,
     B, Hkv, G, M, D = q.shape
     ell = kb.shape[3]
     k_star = idx.shape[-1]
+    dims = idx.shape
 
     def q_map(b, h, g, j, idx_ref):
         return (b, h, g, 0, 0)
 
     def kv_map(b, h, g, j, idx_ref):
-        return (b, h, jnp.maximum(idx_ref[b, h, g, j], 0), 0, 0)
+        return (b, h, jnp.maximum(_sel_id(idx_ref, b, h, g, j, dims), 0), 0, 0)
 
     def tok_map(b, h, g, j, idx_ref):
-        return (b, jnp.maximum(idx_ref[b, h, g, j], 0), 0)
+        return (b, jnp.maximum(_sel_id(idx_ref, b, h, g, j, dims), 0), 0, 0)
 
     def row_map(b, h, g, j, idx_ref):
-        return (b, h, g, 0)
+        return (b, h, g, 0, 0)
 
     def sel_map(b, h, g, j, idx_ref):
         return (b, h, g, j, 0, 0)
@@ -226,10 +237,10 @@ def _bwd_call(q, kb, vb, idx, tok_bias, do, lse, delta, *, interpret,
             pl.BlockSpec((1, 1, 1, M, D), q_map),
             pl.BlockSpec((1, 1, 1, ell, D), kv_map),
             pl.BlockSpec((1, 1, 1, ell, D), kv_map),
-            pl.BlockSpec((1, 1, ell), tok_map),
+            pl.BlockSpec((1, 1, 1, ell), tok_map),
             pl.BlockSpec((1, 1, 1, M, D), q_map),
-            pl.BlockSpec((1, 1, 1, M), row_map),
-            pl.BlockSpec((1, 1, 1, M), row_map),
+            pl.BlockSpec((1, 1, 1, 1, M), row_map),
+            pl.BlockSpec((1, 1, 1, 1, M), row_map),
         ],
         out_specs=(pl.BlockSpec((1, 1, 1, M, D), q_map),
                    pl.BlockSpec((1, 1, 1, 1, ell, D), sel_map),
@@ -237,14 +248,16 @@ def _bwd_call(q, kb, vb, idx, tok_bias, do, lse, delta, *, interpret,
         scratch_shapes=[pltpu.VMEM((M, D), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=1.0 / (D ** 0.5), k_star=k_star,
+        functools.partial(_bwd_kernel, scale=1.0 / (D ** 0.5), dims=dims,
                           compute=compute),
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((B, Hkv, G, M, D), q.dtype),
                    jax.ShapeDtypeStruct((B, Hkv, G, k_star, ell, D), kb.dtype),
                    jax.ShapeDtypeStruct((B, Hkv, G, k_star, ell, D), vb.dtype)),
+        name="bsa_selection_bwd",
         interpret=interpret,
-    )(idx, q, kb, vb, tok_bias, do, lse, delta)
+    )(idx.reshape(-1), q, kb, vb, tok_bias[:, :, None], do, lse,
+      delta[:, :, :, None])
 
 
 def _scatter_blocks(d_sel, idx, nb: int):
@@ -309,8 +322,30 @@ def selection_attention_kernel_call(q, kb, vb, idx, tok_bias, *,
         interpret = should_interpret()
     if compute is None:
         compute = resolve_compute_dtype(q.dtype)
+    f = _make_vjp(interpret, compute)
     if interpret and q.shape[0] > 1:
         # CPU fallback: per-sample grids keep the interpreter linear in B
-        return interpret_batch_map(_make_vjp(True, compute),
-                                   q, kb, vb, idx, tok_bias)
-    return _make_vjp(interpret, compute)(q, kb, vb, idx, tok_bias)
+        f = functools.partial(interpret_batch_map, f)
+    # the ids of one launch must fit SMEM: split the (B, Hkv) grid axes into
+    # independent launches (every grid cell reads only its own (b, h) slice)
+    B, Hkv, G, k_star = idx.shape
+    bc, hc = _smem_chunks(B, Hkv, G * k_star)
+    return jnp.concatenate([
+        jnp.concatenate([
+            f(q[b:b + bc, h:h + hc], kb[b:b + bc, h:h + hc],
+              vb[b:b + bc, h:h + hc], idx[b:b + bc, h:h + hc],
+              tok_bias[b:b + bc])
+            for h in range(0, Hkv, hc)], axis=1)
+        for b in range(0, B, bc)], axis=0)
+
+
+# Budget for the flat scalar-prefetched ids of one launch: half of the
+# 1 MiB SMEM, leaving the rest to Mosaic's own scalars.
+_SMEM_ID_WORDS = 1 << 17
+
+
+def _smem_chunks(B: int, Hkv: int, per_head: int) -> tuple[int, int]:
+    """(batch, KV-head) chunk sizes whose B·Hkv·G·k* ids fit the budget."""
+    hc = max(1, min(Hkv, _SMEM_ID_WORDS // per_head))
+    bc = max(1, _SMEM_ID_WORDS // (per_head * Hkv)) if hc == Hkv else 1
+    return min(bc, B), hc

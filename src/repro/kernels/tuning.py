@@ -4,8 +4,9 @@ Tile-shape choice dominates sparse-kernel throughput (Block Sparse Flash
 Attention's headline result), so instead of a fixed divisor rule the flash
 kernel's ``(tq, tk)`` tiles come from a three-stage policy:
 
-  1. **Cache hit** — a JSON cache persisted at ``~/.cache/repro/tuning.json``
-     (override with ``$REPRO_TUNING_CACHE``) keyed by
+  1. **Cache hit** — a JSON cache persisted at ``$REPRO_TUNING_CACHE`` (a
+     committed file, or one the caller owns; unset = in-memory only, so
+     nothing outside the checkout decides a tile) keyed by
      ``(kernel, shape-bucket, head_dim, dtype, interpret|compiled)``.  Shape
      buckets are next-power-of-two, so one measurement covers a band of
      ragged lengths.  A hit never re-measures — the second run of any shape
@@ -17,7 +18,7 @@ kernel's ``(tq, tk)`` tiles come from a three-stage policy:
      on concrete throwaway inputs (the Triton-autotune pattern), so jitted
      callers pay it once per bucket, ever.
   3. **Heuristic fallback** — otherwise :func:`heuristic_tile`, a
-     deterministic rule that never degenerates: tiles are clamped to
+     deterministic rule that never degenerates: tiles are lane-aligned in
      ``[pref // 2, pref]`` and callers PAD the axis up to a tile multiple
      (see ``kernels/ops.py``) instead of shrinking the tile to a tiny
      divisor.  Interpret mode (CI) always lands here unless a cache entry
@@ -39,10 +40,10 @@ from pathlib import Path
 __all__ = [
     "ENV_CACHE",
     "ENV_AUTOTUNE",
-    "DEFAULT_CACHE",
     "autotune_enabled",
     "cache_path",
     "clear_memory_cache",
+    "clamp_tile",
     "heuristic_tile",
     "round_up",
     "shape_bucket",
@@ -55,7 +56,6 @@ __all__ = [
 
 ENV_CACHE = "REPRO_TUNING_CACHE"
 ENV_AUTOTUNE = "REPRO_AUTOTUNE"
-DEFAULT_CACHE = "~/.cache/repro/tuning.json"
 
 # In-memory mirror of the JSON file: {path: {key: record}}.  Keyed by path so
 # tests pointing $REPRO_TUNING_CACHE at a tmpdir never see stale state.
@@ -66,8 +66,10 @@ def autotune_enabled() -> bool:
     return os.environ.get(ENV_AUTOTUNE, "") not in ("", "0", "false", "False")
 
 
-def cache_path() -> Path:
-    return Path(os.environ.get(ENV_CACHE) or DEFAULT_CACHE).expanduser()
+def cache_path() -> Path | None:
+    """The persisted cache file, or None (unset: in-memory only)."""
+    env = os.environ.get(ENV_CACHE)
+    return Path(env).expanduser() if env else None
 
 
 def clear_memory_cache() -> None:
@@ -80,7 +82,7 @@ def _load() -> dict:
     key = str(p)
     if key not in _MEM:
         try:
-            _MEM[key] = json.loads(p.read_text())
+            _MEM[key] = json.loads(p.read_text()) if p else {}
         except (OSError, ValueError):
             _MEM[key] = {}
     return _MEM[key]
@@ -89,6 +91,8 @@ def _load() -> dict:
 def _save(cache: dict) -> None:
     p = cache_path()
     _MEM[str(p)] = cache
+    if p is None:
+        return
     try:
         p.parent.mkdir(parents=True, exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=str(p.parent), suffix=".tmp")
@@ -112,26 +116,43 @@ def shape_bucket(n: int) -> int:
     return 1 << max(n - 1, 1).bit_length() if n > 1 else 1
 
 
-def heuristic_tile(n: int, pref: int) -> int:
-    """Tile for an axis of length ``n`` with preference ``pref``.
+LANE = 128                                    # TPU vreg lane width
 
-    Never degenerates: the result is a multiple of 8 (TPU sublane) in
-    ``[min(n', pref) // 2, pref]``.  When the tile does not divide ``n`` the
-    CALLER pads the axis up to a multiple (``kernels/ops.py``) — the old rule
-    of shrinking to the largest divisor collapsed to tile size 1 on prime-ish
-    lengths (e.g. ragged ``bucket_length`` leftovers), serialising the grid.
+
+def heuristic_tile(n: int, pref: int) -> int:
+    """Tile for an axis of length ``n`` with preference ``pref`` (a multiple
+    of ``LANE``).
+
+    Never degenerates, and always lowers for Mosaic: an axis that fits in
+    one tile gets the whole axis rounded up to the 8-row sublane; a longer
+    axis gets a ``LANE`` multiple in ``[pref // 2, pref]`` — a block's last
+    dim must be a multiple of 128 or the whole axis, and the per-key bias
+    and per-row lse blocks carry the tile on that dim.  When the tile does
+    not divide ``n`` the CALLER pads the axis up to a multiple
+    (``kernels/ops.py``) — the old rule of shrinking to the largest divisor
+    collapsed to tile size 1 on prime-ish lengths (e.g. ragged
+    ``bucket_length`` leftovers), serialising the grid.
     """
     if n <= pref:
         return round_up(n, 8)                 # single tile, ≤ 7 padded rows
-    if n % pref == 0:
-        return pref
     best = pref
-    for t in range(pref, pref // 2, -8):      # sublane-aligned divisor search
+    for t in range(pref, pref // 2 - 1, -LANE):   # lane-aligned divisor search
         if n % t == 0:
             return t
         if round_up(n, t) - n < round_up(n, best) - n:
             best = t                          # least padding among candidates
     return best
+
+
+def clamp_tile(t: int, n: int, *, interpret: bool) -> int:
+    """Fit a requested tile to an axis of length ``n``: at most the whole
+    axis (rounded up to the sublane), and — for compiled kernels — a
+    ``LANE`` multiple, the only other block width Mosaic accepts.  The
+    interpreter has no layout rule, so explicit small tiles stay as given
+    there (tests use them to exercise many-tile grids on tiny shapes)."""
+    if not interpret:
+        t = round_up(t, LANE)
+    return min(t, round_up(n, 8))
 
 
 # ---------------------------------------------------------------------------

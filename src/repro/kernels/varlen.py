@@ -47,21 +47,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (NEG_INF, lse_finalize, mma_dtype,
                                   p_from_lse, resolve_compute_dtype,
-                                  should_interpret)
+                                  rows_to_column, should_interpret)
 from repro.kernels.occupancy import ranges_overlap
 
 __all__ = ["flash_attention_varlen_kernel_call"]
 
 
-def _seg_mask(s, qs, ks, *, rep, tq):
+def _seg_mask(s, qs, ks, *, rep):
     """Mask cross-sample (q, k) pairs of one tile to NEG_INF.
 
-    ``qs``: (tq,) query segment ids; ``ks``: (tk,) key segment ids.  Row r
-    of the fused (rep·tq)-row group tile is query position ``r % tq``
+    ``qs``: (1, tq) query segment ids; ``ks``: (1, tk) key segment ids.
+    Row r of the fused (rep·tq)-row group tile is query position ``r % tq``
     (rep-major layout), so all rep heads see the same mask row."""
-    rows = rep * tq
-    qsr = jnp.broadcast_to(qs[None, :], (rep, tq)).reshape(rows, 1)
-    return jnp.where(qsr == ks[None, :], s, NEG_INF)
+    qsr = rows_to_column(jnp.broadcast_to(qs, (rep, qs.shape[1])))
+    return jnp.where(qsr == ks, s, NEG_INF)
 
 
 def _fwd_kernel(qrng, krng, q_ref, k_ref, v_ref, kbias_ref, qs_ref, ks_ref,
@@ -88,7 +87,7 @@ def _fwd_kernel(qrng, krng, q_ref, k_ref, v_ref, kbias_ref, qs_ref, ks_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = s + kbias_ref[0]                               # (Tk,) key-validity bias
-        s = _seg_mask(s, qs_ref[0], ks_ref[0], rep=rep, tq=tq)
+        s = _seg_mask(s, qs_ref[...], ks_ref[...], rep=rep)
 
         m_prev = m_scr[...]                                # (rep·Tq, 1)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -137,11 +136,11 @@ def _dq_kernel(qrng, krng, q_ref, k_ref, v_ref, kbias_ref, qs_ref, ks_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = s + kbias_ref[0]
-        s = _seg_mask(s, qs_ref[0], ks_ref[0], rep=rep, tq=tq)
-        p = p_from_lse(s, lse_ref[0].reshape(rows, 1))     # (rep·Tq, Tk)
+        s = _seg_mask(s, qs_ref[...], ks_ref[...], rep=rep)
+        p = p_from_lse(s, rows_to_column(lse_ref[0]))     # (rep·Tq, Tk)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0].reshape(rows, 1)) * scale
+        ds = p * (dp - rows_to_column(delta_ref[0])) * scale
         dq_scr[...] += jax.lax.dot_general(ds.astype(adt), k.astype(adt),
                                            (((1,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
@@ -175,15 +174,15 @@ def _dkv_kernel(qrng, krng, q_ref, k_ref, v_ref, kbias_ref, qs_ref, ks_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = s + kbias_ref[0]
-        s = _seg_mask(s, qs_ref[0], ks_ref[0], rep=rep, tq=tq)
-        p = p_from_lse(s, lse_ref[0].reshape(rows, 1))
+        s = _seg_mask(s, qs_ref[...], ks_ref[...], rep=rep)
+        p = p_from_lse(s, rows_to_column(lse_ref[0]))
         # (0,)-axis contraction: the GQA group's dK/dV accumulate in-matmul
         dv_scr[...] += jax.lax.dot_general(p.astype(adt), do,
                                            (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0].reshape(rows, 1)) * scale
+        ds = p * (dp - rows_to_column(delta_ref[0])) * scale
         dk_scr[...] += jax.lax.dot_general(
             ds.astype(adt), q_ref[0].astype(adt).reshape(rows, D),
             (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -227,6 +226,7 @@ def _fwd_call(q, k, v, key_bias, qseg, kseg, qrng, krng, *, tq, tk,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((BH, rep, N, D), q.dtype),
                    jax.ShapeDtypeStruct((BH, rep, N), jnp.float32)),
+        name="bsa_varlen_fwd",
         interpret=interpret,
     )(qrng, krng, q, k, v, key_bias, qseg, kseg)
 
@@ -260,6 +260,7 @@ def _bwd_calls(q, k, v, key_bias, qseg, kseg, qrng, krng, do, lse, delta, *,
         functools.partial(_dq_kernel, n_k=n_k, **kw),
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((BH, rep, N, D), q.dtype),
+        name="bsa_varlen_dq",
         interpret=interpret,
     )(qrng, krng, q, k, v, key_bias, qseg, kseg, do, lse, delta)
 
@@ -287,6 +288,7 @@ def _bwd_calls(q, k, v, key_bias, qseg, kseg, qrng, krng, do, lse, delta, *,
         grid_spec=dkv_spec,
         out_shape=(jax.ShapeDtypeStruct((BH, L, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, L, D), v.dtype)),
+        name="bsa_varlen_dkv",
         interpret=interpret,
     )(qrng, krng, q, k, v, key_bias, qseg, kseg, do, lse, delta)
     return dq, dk, dv

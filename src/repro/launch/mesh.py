@@ -8,15 +8,7 @@ leading ``pod`` axis (2 pods = 512 chips) whose collectives cross DCI.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    # jax.sharding.AxisType landed after 0.4.x; older jax only has Auto axes,
-    # so omitting the kwarg there is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"16x16 v5e pod ({need} devices) but only {have} device(s) are "
             "present; use make_local_mesh() (or make_mesh() with an explicit "
             "shape) for smaller hosts")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(n_data: int | None = None, *, axis: str = "data"):
@@ -47,12 +39,14 @@ def make_local_mesh(n_data: int | None = None, *, axis: str = "data"):
     if n < 1 or n > have:
         raise RuntimeError(
             f"make_local_mesh(n_data={n}): {have} device(s) present")
-    return jax.make_mesh((n,), (axis,), devices=jax.devices()[:n],
-                         **_axis_type_kwargs(1))
+    return jax.make_mesh((n,), (axis,), (AxisType.Auto,),
+                         devices=jax.devices()[:n])
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    # Auto axes: shardings come from the specs this repo gives (jax's own
+    # default is Explicit, which would type every array by its sharding)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 # v5e hardware constants (per chip) — used by the roofline analysis

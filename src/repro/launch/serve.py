@@ -22,6 +22,7 @@ from repro.configs import get_config
 from repro.configs.reduce import smoke_config
 from repro.models.api import model_api
 from repro.serving import ServingEngine
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main():
@@ -48,6 +49,7 @@ def main():
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="(--paged) disable cross-request prefix reuse")
     args = ap.parse_args()
+    use_compile_cache()
 
     mcfg = get_config(args.arch)
     if args.smoke:

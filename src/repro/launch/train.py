@@ -21,6 +21,7 @@ from repro.configs.reduce import smoke_config
 from repro.data import ShapeNetCarDataset, lm_batches
 from repro.models.api import model_api
 from repro.runtime import Trainer, TrainerConfig
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main():
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--distributed", action="store_true",
                     help="jax.distributed.initialize() from TPU env")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.distributed:
         jax.distributed.initialize()

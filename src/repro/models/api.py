@@ -45,6 +45,9 @@ class ModelAPI:
     cache_reset_slot: Callable | None = None
     cache_copy_block: Callable | None = None
     has_recurrent_state: bool = False
+    # forward that also returns per-layer selected block ids and replays
+    # batch["select"] when present (BSA point clouds; models.pointcloud)
+    forward_selection: Callable | None = None
 
     @property
     def has_decoder(self) -> bool:
@@ -199,6 +202,10 @@ def _pc_api(mcfg) -> ModelAPI:
                                           offsets=b.get("offsets")),
         make_batch=make_batch,
         batch_specs=batch_specs,
+        forward_selection=lambda p, b: _pc.pc_apply(
+            p, b["feats"], mcfg=mcfg, mask=b.get("mask"),
+            offsets=b.get("offsets"), select=b.get("select"),
+            return_selection=True) if mcfg.attention == "bsa" else None,
     )
 
 

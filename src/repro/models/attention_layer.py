@@ -63,7 +63,8 @@ def _project(p, x, mcfg, positions=None, rope: bool = True):
 
 def attention_layer_apply(p, x, *, mcfg, causal: bool, mask=None,
                           positions=None, rope: bool = True,
-                          erwin_level: int = 0, offsets=None):
+                          erwin_level: int = 0, offsets=None, select=None,
+                          return_selection: bool = False):
     """Full-sequence forward.  x: (B, N, d_model) → (B, N, d_model).
 
     ``offsets`` (S+1,) int32 switches the non-causal BSA path to the
@@ -71,9 +72,20 @@ def attention_layer_apply(p, x, *, mcfg, causal: bool, mask=None,
     row (B == 1) whose samples are concatenated back-to-back at ball-size
     boundaries, and ``mask``'s row marks real tokens.  Other mechanisms
     don't support it (yet) and raise.
+
+    ``return_selection`` (non-causal BSA only) also returns the selection
+    branch's block ids, ``{"indices": (B, G, Hkv, k*)}``; ``select`` (same
+    shape) replays ids instead, adding ``gap``/``flips``
+    (``core.bsa._select_blocks``).
     """
     B, N, _ = x.shape
     q, k, v = _project(p, x, mcfg, positions, rope)
+    want_sel = return_selection or select is not None
+    if want_sel and (mcfg.attention != "bsa" or causal):
+        raise NotImplementedError(
+            "selection ids exist only in non-causal BSA "
+            f"(got attention={mcfg.attention!r}, causal={causal})")
+    aux = {}
     if offsets is not None:
         if mcfg.attention != "bsa" or causal:
             raise NotImplementedError(
@@ -84,13 +96,21 @@ def attention_layer_apply(p, x, *, mcfg, causal: bool, mask=None,
                 f"packed-varlen input must be a single packed row, got B={B}")
         out = bsa_attention_varlen(
             p["bsa"], q[0], k[0], v[0], cfg=mcfg.bsa, offsets=offsets,
-            mask=None if mask is None else mask[0], x=x[0])[None]
+            mask=None if mask is None else mask[0], x=x[0],
+            return_aux=want_sel, select=None if select is None else select[0])
+        if want_sel:
+            out, aux = out
+            aux["indices"] = aux["indices"][None]
+        out = out[None]
     elif mcfg.attention == "bsa":
         if causal:
             out = nsa_causal_attention(p["bsa"], q, k, v, cfg=mcfg.bsa,
                                        mask=mask, x=x)
         else:
-            out = bsa_attention(p["bsa"], q, k, v, cfg=mcfg.bsa, mask=mask, x=x)
+            out = bsa_attention(p["bsa"], q, k, v, cfg=mcfg.bsa, mask=mask,
+                                x=x, return_aux=want_sel, select=select)
+            if want_sel:
+                out, aux = out
     elif mcfg.attention == "erwin":
         out = erwin_attention(q, k, v, ball_size=mcfg.bsa.ball_size,
                               level=erwin_level, mask=mask,
@@ -99,6 +119,9 @@ def attention_layer_apply(p, x, *, mcfg, causal: bool, mask=None,
         out = full_attention(q, k, v, mask=mask, causal=causal,
                              backend=mcfg.bsa.backend)
     out = out.reshape(B, N, mcfg.n_heads * mcfg.resolved_head_dim)
+    if want_sel:
+        return dense(p["wo"], out), {key: aux[key] for key in
+                                     ("indices", "gap", "flips") if key in aux}
     return dense(p["wo"], out)
 
 

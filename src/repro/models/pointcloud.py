@@ -43,46 +43,58 @@ def _layer_init(key, mcfg, pd):
 
 
 def pc_apply(params, feats, *, mcfg, mask=None, erwin_level_of=None,
-             offsets=None):
+             offsets=None, select=None, return_selection: bool = False):
     """feats: (B, N, in_dim) ball-ordered; mask: (B, N).  → (B, N, out_dim).
 
     ``offsets`` (S+1,) int32 selects the packed-varlen layout (docs/varlen.md):
     feats is then ONE packed row (B=1) of concatenated samples and every
-    attention layer runs segment-isolated with no dummy batch slots."""
+    attention layer runs segment-isolated with no dummy batch slots.
+
+    ``return_selection`` (BSA only) also returns every layer's selected
+    block ids, ``{"indices": (n_layers, B, G, Hkv, k*)}``; ``select`` (that
+    shape) replays ids instead — e.g. another layout's or backend's, so two
+    runs can be compared without a near-tie in top-k breaking differently —
+    and adds per-layer ``gap``/``flips`` (``core.bsa._select_blocks``)."""
     cdt = mcfg.cdtype()
     x = dense(params["embed"], feats.astype(cdt))
     x = constrain(x, "batch", "seq_res", "d_model")
+    want_sel = return_selection or select is not None
 
-    def layer(lp, x, level):
+    def layer(lp, x, level, sel=None):
         h = rmsnorm(lp["norm1"], x, mcfg.norm_eps)
         h = attention_layer_apply(lp["attn"], h, mcfg=mcfg, causal=False,
                                   mask=mask, positions=None, rope=False,
-                                  erwin_level=level, offsets=offsets)
+                                  erwin_level=level, offsets=offsets,
+                                  select=sel, return_selection=want_sel)
+        h, sel = h if want_sel else (h, None)
         x = x + h
         h = rmsnorm(lp["norm2"], x, mcfg.norm_eps)
         x = x + swiglu(lp["ffn"], h)
-        return constrain(x, "batch", "seq_res", "d_model")
+        return constrain(x, "batch", "seq_res", "d_model"), sel
 
     if mcfg.attention == "erwin" and erwin_level_of is None:
         # Erwin's coarsen/refine cycle: levels 0,1,2,1,0,...
         cyc = [0, 1, 2, 1]
         erwin_level_of = lambda i: cyc[i % len(cyc)]
 
+    sels = None
     if erwin_level_of is not None:
         # per-layer levels differ → unrolled loop (baseline only, 18 layers)
         for i in range(mcfg.n_layers):
             lp = jax.tree.map(lambda t: t[i], params["layers"])
-            x = layer(lp, x, erwin_level_of(i))
+            x, _ = layer(lp, x, erwin_level_of(i))
     else:
         fn = functools.partial(layer, level=0)
         if mcfg.remat:
             fn = jax.checkpoint(fn)
-        def body(x, lp):
-            return fn(lp, x), None
-        x, _ = jax.lax.scan(body, x, params["layers"])
+        def body(x, xs):
+            lp, sel = xs
+            return fn(lp, x, sel=sel)
+        x, sels = jax.lax.scan(body, x, (params["layers"], select))
 
     x = rmsnorm(params["final_norm"], x, mcfg.norm_eps)
-    return dense(params["head"], x).astype(jnp.float32)
+    out = dense(params["head"], x).astype(jnp.float32)
+    return (out, sels) if want_sel else out
 
 
 def pc_loss(params, batch, *, mcfg):

@@ -61,7 +61,7 @@ def make_compressed_grad_fn(loss_fn, mesh, *, axis_name: str = "pod"):
     """Wrap a loss into a shard_map'd per-pod grad + compressed cross-pod
     reduction.  Gradients w.r.t. REPLICATED params; batch sharded over pod."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def per_pod(params, batch, err):
         (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
@@ -78,4 +78,4 @@ def make_compressed_grad_fn(loss_fn, mesh, *, axis_name: str = "pod"):
         per_pod, mesh=mesh,
         in_specs=(P(), P(axis_name), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False)
+        check_vma=False)

@@ -80,6 +80,13 @@ class Trainer:
                 opt_state = jax.device_put(opt_state, self.o_sh)
         return params, opt_state
 
+    def lower_step(self, params, opt_state, batch):
+        """The jitted step ``fit`` runs, lowered for these arguments (AOT:
+        ``.compile()`` gives the executable, whose ``.as_text()`` shows
+        which kernels the step launches)."""
+        with self._ctx():
+            return self._jit_step.lower(params, opt_state, batch)
+
     def _ctx(self):
         if self.mesh is not None:
             return axis_rules(self.mesh, self.rules)

@@ -42,8 +42,7 @@ import numpy as np
 
 from repro.core.backend import use_backend
 from repro.core.balltree import (bucket_length, pack_ragged, pack_varlen,
-                                 build_balltree_permutations, unpack_ragged,
-                                 unpack_varlen)
+                                 build_balltree_permutations)
 from repro.launch.steps import (make_paged_serve_step, make_paged_serve_window,
                                 make_serve_step)
 from repro.serving.paged_cache import PagedKVCache
@@ -494,26 +493,46 @@ class GeometryEngine:
         self.layout = layout
         self.ball_size = api.mcfg.bsa.ball_size
         self._fwd = jax.jit(api.forward)
+        self._fwd_sel = (jax.jit(api.forward_selection)
+                         if api.forward_selection else None)
         self.clouds_served = 0
         self.points_served = 0
         self.predict_time = 0.0
 
-    def predict(self, clouds) -> list[np.ndarray]:
+    def predict(self, clouds, *, select=None, return_selection: bool = False):
         """clouds: sequence of ``(points (n_i, d), feats (n_i, in_dim))``
         pairs (or dicts with those keys).  Returns one (n_i, out_dim) array
-        per cloud, rows in the CALLER's original point order."""
+        per cloud, rows in the CALLER's original point order.
+
+        ``return_selection`` (BSA models) returns ``(predictions,
+        selections)``: per cloud ``{"indices": (n_layers, G_i, Hkv, k*)}``,
+        the selected block ids of its G_i query groups in its own ball
+        order (−1: invalid), independent of layout and batch.  ``select``
+        takes a list in that form (e.g. another engine's) and REPLAYS it
+        (``models.pointcloud.pc_apply``), adding the cloud's batch's
+        per-layer ``gap``/``flips``: two layouts or backends then compare
+        without a near-tie in top-k breaking differently in each."""
         clouds = [(c["points"], c["feats"]) if isinstance(c, dict) else c
                   for c in clouds]
+        want_sel = return_selection or select is not None
+        if want_sel and self._fwd_sel is None:
+            raise NotImplementedError(
+                f"{self.api.mcfg.attention!r} attention selects no blocks")
         results: list[np.ndarray] = []
+        selections: list[dict] = []
         t0 = time.time()
         for s in range(0, len(clouds), self.batch_slots):
-            results.extend(self._predict_batch(clouds[s:s + self.batch_slots]))
+            preds, sels = self._predict_batch(
+                clouds[s:s + self.batch_slots], want_sel,
+                None if select is None else select[s:s + self.batch_slots])
+            results.extend(preds)
+            selections.extend(sels)
         self.predict_time += time.time() - t0
         self.clouds_served += len(clouds)
         self.points_served += sum(int(np.asarray(p).shape[0]) for p, _ in clouds)
-        return results
+        return (results, selections) if want_sel else results
 
-    def _predict_batch(self, chunk) -> list[np.ndarray]:
+    def _predict_batch(self, chunk, want_sel=False, select=None):
         pts_list = [np.asarray(p) for p, _ in chunk]
         fts_list = [np.asarray(f, np.float32) for _, f in chunk]
         perms = build_balltree_permutations(pts_list, self.ball_size)
@@ -522,38 +541,74 @@ class GeometryEngine:
             feats, offsets, mask = pack_varlen(
                 ordered, self.ball_size, pad_to=self.pad_to,
                 max_samples=self.batch_slots)
-            with _backend_scope(self.backend, self._mesh):
-                pred = self._fwd(self.params,
-                                 {"feats": jnp.asarray(feats)[None],
-                                  "mask": jnp.asarray(mask)[None],
-                                  "offsets": jnp.asarray(offsets)})
-            per_cloud = unpack_varlen(np.asarray(pred)[0],
-                                      offsets[:len(chunk) + 1], mask)
-            out = []
-            for rows, perm in zip(per_cloud, perms):
-                unperm = np.empty_like(rows)
-                unperm[perm] = rows                # ball order → original order
-                out.append(unperm)
-            return out
-        target = self.pad_to or bucket_length(
-            max(f.shape[0] for f in ordered), self.ball_size)
-        # fully-masked dummy slots keep B static for the final short batch
-        # (every branch returns exact zeros for an all-invalid sample)
-        pad_slots = self.batch_slots - len(chunk)
-        if pad_slots > 0:
-            ordered += [np.zeros((1, ordered[0].shape[1]), np.float32)] * pad_slots
-        feats, mask = pack_ragged(ordered, self.ball_size, pad_to=target)
-        if pad_slots > 0:
-            mask[len(chunk):] = False
+            batch = {"feats": jnp.asarray(feats)[None],
+                     "mask": jnp.asarray(mask)[None],
+                     "offsets": jnp.asarray(offsets)}
+            # (batch row, first token, ball-padded length) of every cloud
+            places = [(0, int(a), int(b - a)) for a, b in
+                      zip(offsets[:len(chunk)], offsets[1:len(chunk) + 1])]
+        else:
+            target = self.pad_to or bucket_length(
+                max(f.shape[0] for f in ordered), self.ball_size)
+            # fully-masked dummy slots keep B static for the final short
+            # batch (every branch returns exact zeros for an all-invalid one)
+            pad_slots = self.batch_slots - len(chunk)
+            if pad_slots > 0:
+                ordered += [np.zeros((1, ordered[0].shape[1]),
+                                     np.float32)] * pad_slots
+            feats, mask = pack_ragged(ordered, self.ball_size, pad_to=target)
+            if pad_slots > 0:
+                mask[len(chunk):] = False
+            batch = {"feats": jnp.asarray(feats), "mask": jnp.asarray(mask)}
+            places = [(i, 0, bucket_length(len(f), self.ball_size,
+                                           geometric=False))
+                      for i, f in enumerate(fts_list)]
+        if select is not None:
+            batch["select"] = jnp.asarray(self._replay_ids(
+                select, places, *batch["mask"].shape))
         with _backend_scope(self.backend, self._mesh):
-            pred = self._fwd(self.params, {"feats": jnp.asarray(feats),
-                                           "mask": jnp.asarray(mask)})
-        per_cloud = unpack_ragged(np.asarray(pred), mask)[:len(chunk)]
+            if want_sel:
+                pred, sel = self._fwd_sel(self.params, batch)
+            else:
+                pred = self._fwd(self.params, batch)
+        pred = np.asarray(pred)
         out = []
-        for rows, perm in zip(per_cloud, perms):
-            unperm = np.empty_like(rows)
-            unperm[perm] = rows                    # ball order → original order
+        for (row, start, _), f, perm in zip(places, fts_list, perms):
+            unperm = np.empty((len(f),) + pred.shape[2:], pred.dtype)
+            unperm[perm] = pred[row, start:start + len(f)]  # ball → caller order
             out.append(unperm)
+        return out, (self._cloud_ids(sel, places, batch["mask"].shape[1])
+                     if want_sel else [])
+
+    def _replay_ids(self, select, places, n_rows, n_tokens):
+        """Per-cloud ids (n_layers, G_i, Hkv, k*) → the batch's replay array
+        (n_layers, rows, G, Hkv, k*): packed-axis block ids, −1 elsewhere."""
+        ids = [np.asarray(s["indices"]) for s in select]
+        per_group = places[0][2] // ids[0].shape[1]
+        n_layers, _, hkv, k_star = ids[0].shape
+        ell = self.api.mcfg.bsa.cmp_block
+        if k_star != min(self.api.mcfg.bsa.top_k, n_tokens // ell):
+            raise ValueError(f"{k_star} ids per group cannot replay where "
+                             f"{n_tokens // ell} blocks bound top-k")
+        out = np.full((n_layers, n_rows, n_tokens // per_group, hkv, k_star),
+                      -1, np.int32)
+        for cloud, (row, start, _) in zip(ids, places):
+            g0 = start // per_group
+            out[:, row, g0:g0 + cloud.shape[1]] = np.where(
+                cloud >= 0, cloud + start // ell, -1)
+        return out
+
+    def _cloud_ids(self, sel, places, n_tokens):
+        """The batch's ids → per cloud, in its own block coordinates."""
+        idx = np.asarray(sel["indices"])              # (n_layers, rows, G, ..)
+        per_group = n_tokens // idx.shape[2]
+        ell = self.api.mcfg.bsa.cmp_block
+        stats = {k: np.asarray(sel[k]) for k in ("gap", "flips") if k in sel}
+        out = []
+        for row, start, length in places:
+            ids = idx[:, row, start // per_group:(start + length) // per_group]
+            out.append({"indices": np.where(ids >= 0, ids - start // ell, -1),
+                        **stats})
         return out
 
     @property

@@ -20,6 +20,7 @@ def _run(src: str, n_dev: int = 8) -> str:
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(src)],
         env={"XLA_FLAGS": f"--xla_force_host_platform_device_count={n_dev}",
+             "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
         capture_output=True, text=True, timeout=900, cwd=".")
     assert out.returncode == 0, out.stderr[-3000:]
@@ -101,7 +102,7 @@ def test_pipeline_matches_sequential():
 def test_compressed_psum_close_to_exact():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.optim.compress import compressed_psum
@@ -115,7 +116,7 @@ def test_compressed_psum_close_to_exact():
 
         total, resid = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
                                  out_specs=(P("pod"), P("pod")),
-                                 check_rep=False)(g, jnp.zeros_like(g))
+                                 check_vma=False)(g, jnp.zeros_like(g))
         exact = g.sum(0)
         rel = float(jnp.abs(total[0] - exact).max() / (jnp.abs(exact).max()))
         print("REL", rel)
@@ -462,7 +463,7 @@ def test_ring_flash_parity_8dev():
     out = _run("""
         import numpy as np
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.backend import get_backend
         from repro.distributed import ring
@@ -490,7 +491,7 @@ def test_ring_flash_parity_8dev():
                     q, k, v, kb, axis=axis, p=p, causal=causal, live=live)
                 return shard_map(body, mesh=mesh,
                                  in_specs=(seq, seq, seq, seq),
-                                 out_specs=seq, check_rep=False)(q, k, v, kb)
+                                 out_specs=seq, check_vma=False)(q, k, v, kb)
 
             ref = jb.flash(q, k, v, key_valid=mask, causal=causal)
             e = float(jnp.abs(run(q, k, v) - ref).max())
@@ -515,45 +516,54 @@ def test_ring_selection_parity_8dev():
     out = _run("""
         import numpy as np
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.backend import get_backend
         from repro.distributed import ring
         from repro.launch.mesh import make_local_mesh
 
-        mesh, axis, p = make_local_mesh(8), "data", 8
-        rng = np.random.default_rng(0)
-        B, N, Hq, Hkv, D = 2, 128, 4, 2, 16
-        ell, g, k_star = 8, 16, 4
-        G, nb = N // g, N // ell
-        q = jnp.asarray(rng.normal(size=(B, N, Hq, D)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, N, Hkv, D)), jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, N, Hkv, D)), jnp.float32)
-        mask = jnp.asarray(rng.random((B, N)) > 0.2)
-        ti = jnp.asarray(rng.integers(0, nb, size=(B, G, Hkv, k_star)), jnp.int32)
-        sv = jnp.asarray(rng.random((B, G, Hkv, k_star)) > 0.25)
+        axis = "data"
         jb = get_backend("jnp")
         seq = P(None, axis)
+        # (p, B, N, ell, g): the second mesh puts an EMPTY group (no valid
+        # selection, like NSA's first causal group) beside live ones on a
+        # shard — its backward once divided by tiny**2 and returned NaN
+        for p, B, N, ell, g in ((8, 2, 128, 8, 16), (4, 1, 256, 8, 8)):
+            mesh = make_local_mesh(p)
+            rng = np.random.default_rng(0)
+            Hq, Hkv, D, k_star = 4, 2, 16, 4
+            G, nb = N // g, N // ell
+            q = jnp.asarray(rng.normal(size=(B, N, Hq, D)), jnp.float32)
+            k = jnp.asarray(rng.normal(size=(B, N, Hkv, D)), jnp.float32)
+            v = jnp.asarray(rng.normal(size=(B, N, Hkv, D)), jnp.float32)
+            mask = jnp.asarray(rng.random((B, N)) > 0.2)
+            ti = jnp.asarray(rng.integers(0, nb, size=(B, G, Hkv, k_star)),
+                             jnp.int32)
+            sv = jnp.asarray(rng.random((B, G, Hkv, k_star)) > 0.25)
+            if p == 4:
+                sv = sv.at[:, 0].set(False)
 
-        def run(q, k, v):
-            body = lambda q, ti, sv, k, v, m, qv: ring.ring_selection(
-                q, k, v, ti, sv, m, qv, axis=axis, p=p,
-                block_size=ell, group_size=g)
-            return shard_map(body, mesh=mesh,
-                             in_specs=(seq,) * 7, out_specs=seq,
-                             check_rep=False)(q, ti, sv, k, v, mask, mask)
+            def run(q, k, v):
+                body = lambda q, ti, sv, k, v, m, qv: ring.ring_selection(
+                    q, k, v, ti, sv, m, qv, axis=axis, p=p,
+                    block_size=ell, group_size=g)
+                return shard_map(body, mesh=mesh,
+                                 in_specs=(seq,) * 7, out_specs=seq,
+                                 check_vma=False)(q, ti, sv, k, v, mask, mask)
 
-        ref = jb.selection(q, k, v, ti, sv, mask, block_size=ell, group_size=g)
-        e = float(jnp.abs(run(q, k, v) - ref).max())
-        w = jnp.asarray(np.random.default_rng(1).normal(size=ref.shape))
-        g1 = jax.grad(lambda q, k, v: (run(q, k, v) * w).sum(),
-                      argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(lambda q, k, v: (jb.selection(
-            q, k, v, ti, sv, mask, block_size=ell, group_size=g) * w).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-        ge = max(float(jnp.abs(a - b).max()) for a, b in zip(g1, g2))
-        print("fwd", e, "grad", ge)
-        assert e < 1e-5 and ge < 1e-5, (e, ge)
+            ref = jb.selection(q, k, v, ti, sv, mask, block_size=ell,
+                               group_size=g)
+            e = float(jnp.abs(run(q, k, v) - ref).max())
+            w = jnp.asarray(np.random.default_rng(1).normal(size=ref.shape))
+            g1 = jax.grad(lambda q, k, v: (run(q, k, v) * w).sum(),
+                          argnums=(0, 1, 2))(q, k, v)
+            g2 = jax.grad(lambda q, k, v: (jb.selection(
+                q, k, v, ti, sv, mask, block_size=ell, group_size=g) * w).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+            ge = max(float(jnp.abs(a - b).max()) for a, b in zip(g1, g2))
+            print(p, "fwd", e, "grad", ge)
+            assert all(bool(jnp.isfinite(x).all()) for x in g1)
+            assert e < 1e-5 and ge < 1e-5, (p, e, ge)
         print("RING_SEL_OK")
     """)
     assert "RING_SEL_OK" in out

@@ -288,6 +288,44 @@ def test_grad_parity_dtype_sweep(kernel, dtype):
                                    np.asarray(r, np.float32), **tol)
 
 
+@pytest.mark.parametrize("B,split", [(1, "heads"), (2, "heads"), (2, "batch")])
+def test_selection_smem_split_parity(monkeypatch, B, split):
+    """Launches split over (batch, KV head) so that each launch's ids fit
+    SMEM give the unsplit kernel's result, forward and gradients."""
+    from repro.kernels import selection
+
+    N, Hkv, rep, D, ell, g, ks = 128, 2, 2, 32, 8, 8, 4
+    G, nb = N // g, N // ell
+    q, k, v, w = _qkvw(B, N, Hkv * rep, Hkv, D)
+    mask = _mask(B, N, True)
+    k1, k2 = jax.random.split(jax.random.fold_in(KEY, 22))
+    idx = jax.random.randint(k1, (B, G, Hkv, ks), 0, nb)
+    valid = jax.random.bernoulli(k2, 0.85, (B, G, Hkv, ks))
+
+    def loss(q, k, v):
+        out = ops.selection_attention(q, k, v, idx, valid, mask,
+                                      block_size=ell, group_size=g)
+        return jnp.sum(out * w)
+
+    run = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    want = run(q, k, v)
+    # budget of one KV head's ids ("heads") or of one sample's ("batch")
+    budget = G * ks * (1 if split == "heads" else Hkv)
+    monkeypatch.setattr(selection, "_SMEM_ID_WORDS", budget)
+    assert selection._smem_chunks(B, Hkv, G * ks) == (
+        1, 1 if split == "heads" else Hkv)
+    selection.selection_attention_kernel_call.clear_cache()
+    try:
+        got = run(q, k, v)
+    finally:
+        selection.selection_attention_kernel_call.clear_cache()
+    _assert_grads_close(got, want)
+    ref_loss = lambda q, k, v: jnp.sum(ref.selection_attention_ref(
+        q, k, v, idx, valid, mask, block_size=ell, group_size=g) * w)
+    _assert_grads_close(got, jax.value_and_grad(ref_loss, argnums=(0, 1, 2))(
+        q, k, v))
+
+
 def test_kernel_train_step_is_jittable():
     """A jitted fwd+bwd step on the kernel path compiles and yields finite grads."""
     B, N, Hq, Hkv, D, dm = 1, 128, 4, 2, 32, 64

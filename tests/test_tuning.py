@@ -54,6 +54,18 @@ def test_heuristic_tile_small_axis_pads_to_sublane():
     assert tuning.heuristic_tile(512, 256) == 256  # exact divisor kept
 
 
+def test_heuristic_tile_is_lane_aligned_past_one_tile():
+    """Mosaic takes a block's last dim only as a multiple of 128 or the
+    whole axis, and the key-bias / lse blocks carry the tile there: the
+    compression branch's 480 pooled keys once got a 240 tile."""
+    assert tuning.heuristic_tile(480, 256) == 256
+    for n in (257, 300, 480, 1000, 3840, 4097):
+        assert tuning.heuristic_tile(n, 256) % tuning.LANE == 0
+    assert tuning.clamp_tile(64, 300, interpret=False) == 128
+    assert tuning.clamp_tile(64, 300, interpret=True) == 64    # test tiles stay
+    assert tuning.clamp_tile(256, 100, interpret=False) == 104  # whole axis
+
+
 def test_shape_bucket():
     assert tuning.shape_bucket(1) == 1
     assert tuning.shape_bucket(256) == 256
@@ -178,6 +190,21 @@ def test_autotune_off_uses_heuristic_and_writes_nothing():
     assert tiles == (tuning.heuristic_tile(257, 256),
                      tuning.heuristic_tile(64, 256))
     assert not tuning.cache_path().exists()
+
+
+def test_unset_cache_env_keeps_tiles_in_memory(monkeypatch, tmp_path):
+    """Without $REPRO_TUNING_CACHE nothing outside the caller's choice is
+    read or written: measured tiles live in memory only."""
+    monkeypatch.delenv(tuning.ENV_CACHE)
+    monkeypatch.setenv(tuning.ENV_AUTOTUNE, "1")
+    monkeypatch.setenv("HOME", str(tmp_path))
+    assert tuning.cache_path() is None
+    kw = dict(n_q=300, n_k=300, d=32, dtype=jnp.float32, interpret=True)
+    got = tuning.get_tiles("flash", measure=lambda tq, tk: 1.0 if (tq, tk) != (
+        128, 128) else 0.1, **kw)
+    assert got == (128, 128)
+    assert tuning.get_tiles("flash", measure=None, **kw) == (128, 128)
+    assert not any(tmp_path.rglob("*"))
 
 
 def test_tune_flash_end_to_end(monkeypatch):

@@ -336,6 +336,55 @@ def test_geometry_engine_packed_matches_padded():
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
 
 
+def test_geometry_engine_selection_replay():
+    """Per-cloud selection ids are layout-free: the packed engine's ids,
+    replayed by the padded one, are a top-k there too (gap 0, no flips) and
+    give the same predictions; replaying wrong blocks shows in the gap."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.api import model_api
+    from repro.serving import GeometryEngine
+
+    mcfg = get_config("shapenet-bsa").scaled(
+        n_layers=2, d_model=32, n_heads=2, head_dim=16, n_kv_heads=2, d_ff=64)
+    mcfg = mcfg.scaled(bsa=dataclasses.replace(mcfg.bsa, ball_size=16,
+                                               local_window=16, backend="jnp"))
+    api = model_api(mcfg)
+    params = api.init(jax.random.PRNGKey(0))
+    eng_pk = GeometryEngine(api, params, batch_slots=3)
+    eng_pad = GeometryEngine(api, params, batch_slots=1, layout="padded",
+                             pad_to=64)
+    rng = np.random.default_rng(7)
+    clouds = [(rng.standard_normal((n, 3)).astype(np.float32),
+               rng.standard_normal((n, mcfg.in_dim)).astype(np.float32))
+              for n in (40, 64, 33, 50)]
+
+    out_pk, sel_pk = eng_pk.predict(clouds, return_selection=True)
+    for a, b in zip(out_pk, eng_pk.predict(clouds)):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-6)
+    for (_, f), sel in zip(clouds, sel_pk):
+        n_groups = -(-len(f) // 16) * 16 // 8       # ball-padded length / ℓ
+        assert sel["indices"].shape == (2, n_groups, 2, 4)
+        assert set(sel) == {"indices"}
+    _, sel_pad = eng_pad.predict(clouds, return_selection=True)
+    out_re, sel_re = eng_pad.predict(clouds, select=sel_pk)
+    for a, b, s_pk, s_pad, s_re in zip(out_pk, out_re, sel_pk, sel_pad,
+                                       sel_re):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+        np.testing.assert_array_equal(np.sort(s_pk["indices"], -1),
+                                      np.sort(s_pad["indices"], -1))
+        np.testing.assert_array_equal(s_re["indices"], s_pk["indices"])
+        assert s_re["gap"].shape == (2,) and s_re["gap"].max() <= 1e-6
+        assert s_re["flips"].sum() == 0
+
+    # blocks one position over are not a top-k: the gap says so
+    wrong = [{"indices": np.roll(s["indices"], 1, axis=1)} for s in sel_pk]
+    _, sel_wrong = eng_pad.predict(clouds, select=wrong)
+    assert max(s["gap"].max() for s in sel_wrong) > 1e-2
+    assert sum(s["flips"].sum() for s in sel_wrong) > 0
+
+
 def test_pc_model_offsets_path_matches_padded():
     """pc_apply with a packed row + offsets == bucket-padded rows."""
     import dataclasses
