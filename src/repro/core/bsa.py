@@ -14,6 +14,11 @@ scoring (Eq. 13–14), group compression (Eq. 15) and own-ball masking (§3.2)
 are all implemented and switchable via :class:`repro.core.config.BSAConfig`.
 
 All functions are shape-polymorphic over GQA: q has ``Hq = Hkv * rep`` heads.
+
+Every device op of a BSA call runs under ``jax.named_scope("bsa")`` and one
+branch scope: ``ball``, ``compression``, ``selection`` (``score``, ``topk``,
+``attend``) or ``combine``.  Scopes name the ops in the profiler's trace
+(HLO ``op_name`` metadata) and leave instruction and kernel names alone.
 """
 
 from __future__ import annotations
@@ -245,15 +250,19 @@ def _selection_branch(params, q, k, v, k_cmp, blk_valid, mask, cfg: BSAConfig,
     ell = cfg.cmp_block
     nb = N // ell
 
-    scores = _selection_scores(params, q, k_cmp, blk_valid, mask, cfg)  # (B,G,Hkv,NB)
+    with jax.named_scope("score"):
+        scores = _selection_scores(params, q, k_cmp, blk_valid, mask,
+                                   cfg)                            # (B,G,Hkv,NB)
     G = scores.shape[1]
     g = N // G
-    top_idx, sel_valid, stats = _select_blocks(
-        scores, min(cfg.top_k, nb), select)                        # (B,G,Hkv,k*)
+    with jax.named_scope("topk"):
+        top_idx, sel_valid, stats = _select_blocks(
+            scores, min(cfg.top_k, nb), select)                    # (B,G,Hkv,k*)
 
-    out = backend.selection(q, k, v, top_idx, sel_valid, mask,
-                            block_size=ell, group_size=g,
-                            chunk_tokens=cfg.jnp_chunk_tokens)
+    with jax.named_scope("attend"):
+        out = backend.selection(q, k, v, top_idx, sel_valid, mask,
+                                block_size=ell, group_size=g,
+                                chunk_tokens=cfg.jnp_chunk_tokens)
     return out, jnp.where(sel_valid, top_idx, -1), stats
 
 
@@ -281,39 +290,43 @@ def bsa_attention(params: dict, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     B, N, Hq, D = q.shape
     assert k.shape[:2] == (B, N) and v.shape == k.shape
     assert Hq % k.shape[2] == 0, "q heads must be a multiple of kv heads"
+    with jax.named_scope("bsa"):
+        # precision contract: under score_dtype="bfloat16" the branch inputs go
+        # in bf16 (kernels keep QK^T/PV operands bf16, accumulate fp32) and the
+        # combined output is cast back to the caller's dtype at the end.
+        in_dtype = q.dtype
+        q, k, v = score_dtype_cast(cfg, q, k, v)
 
-    # precision contract: under score_dtype="bfloat16" the branch inputs go
-    # in bf16 (kernels keep QK^T/PV operands bf16, accumulate fp32) and the
-    # combined output is cast back to the caller's dtype at the end.
-    in_dtype = q.dtype
-    q, k, v = score_dtype_cast(cfg, q, k, v)
+        # logical-axis hints for the sharded backend / GSPMD: no-ops outside an
+        # axis_rules context (mesh_context enters one), so single-device runs
+        # are untouched; under a mesh the glue between shard_mapped ops keeps
+        # the sequence dim on the mesh axis instead of bouncing to replicated
+        q = constrain(q, "batch", "seq_sp", None, None)
+        k = constrain(k, "batch", "seq_sp", None, None)
+        v = constrain(v, "batch", "seq_sp", None, None)
 
-    # logical-axis hints for the sharded backend / GSPMD: no-ops outside an
-    # axis_rules context (mesh_context enters one), so single-device runs
-    # are untouched; under a mesh the glue between shard_mapped ops keeps
-    # the sequence dim on the mesh axis instead of bouncing to replicated
-    q = constrain(q, "batch", "seq_sp", None, None)
-    k = constrain(k, "batch", "seq_sp", None, None)
-    v = constrain(v, "batch", "seq_sp", None, None)
+        bk = resolve_branch_backends(cfg)
+        with jax.named_scope("ball"):
+            out_ball = _ball_branch(q, k, v, mask, cfg, bk["ball"])
+        with jax.named_scope("compression"):
+            out_cmp, k_cmp, v_cmp, blk_valid = _compression_branch(
+                params, q, k, v, mask, cfg, bk["cmp"])
+        with jax.named_scope("selection"):
+            out_slc, top_idx, stats = _selection_branch(
+                params, q, k, v, k_cmp, blk_valid, mask, cfg, bk["slc"], select)
 
-    bk = resolve_branch_backends(cfg)
-    out_ball = _ball_branch(q, k, v, mask, cfg, bk["ball"])
-    out_cmp, k_cmp, v_cmp, blk_valid = _compression_branch(
-        params, q, k, v, mask, cfg, bk["cmp"])
-    out_slc, top_idx, stats = _selection_branch(
-        params, q, k, v, k_cmp, blk_valid, mask, cfg, bk["slc"], select)
-
-    gates = gate_values(params["gates"], cfg, x, Hq)
-    # fused epilogue: gate + sum + query-mask in one pass (the pallas
-    # backends run kernels/epilogue.py; others fall back to the jnp ref)
-    out = get_combine(bk["ball"])(
-        (out_ball, out_cmp, out_slc),
-        (gates["ball"], gates["cmp"], gates["slc"]), mask).astype(in_dtype)
-    out = constrain(out, "batch", "seq_sp", None, None)
-    if return_aux:
-        return out, {"ball": out_ball, "cmp": out_cmp, "slc": out_slc,
-                     "indices": top_idx, "gates": gates, **stats}
-    return out
+        with jax.named_scope("combine"):
+            gates = gate_values(params["gates"], cfg, x, Hq)
+            # fused epilogue: gate + sum + query-mask in one pass (the pallas
+            # backends run kernels/epilogue.py; others fall back to the jnp ref)
+            out = get_combine(bk["ball"])(
+                (out_ball, out_cmp, out_slc),
+                (gates["ball"], gates["cmp"], gates["slc"]), mask).astype(in_dtype)
+            out = constrain(out, "batch", "seq_sp", None, None)
+        if return_aux:
+            return out, {"ball": out_ball, "cmp": out_cmp, "slc": out_slc,
+                         "indices": top_idx, "gates": gates, **stats}
+        return out
 
 
 def bsa_attention_varlen(params: dict, q: jnp.ndarray, k: jnp.ndarray,
@@ -346,56 +359,67 @@ def bsa_attention_varlen(params: dict, q: jnp.ndarray, k: jnp.ndarray,
     T, Hq, D = q.shape
     assert k.shape[0] == T and v.shape == k.shape
     assert Hq % k.shape[1] == 0, "q heads must be a multiple of kv heads"
-    # precision contract — see bsa_attention
-    in_dtype = q.dtype
-    q, k, v = score_dtype_cast(cfg, q, k, v)
-    ell = cfg.cmp_block
-    nb = T // ell
-    ct = cfg.jnp_chunk_tokens
-    maskb = None if mask is None else mask[None]
+    with jax.named_scope("bsa"):
+        # precision contract — see bsa_attention
+        in_dtype = q.dtype
+        q, k, v = score_dtype_cast(cfg, q, k, v)
+        ell = cfg.cmp_block
+        nb = T // ell
+        ct = cfg.jnp_chunk_tokens
+        maskb = None if mask is None else mask[None]
 
-    bk = resolve_branch_backends(cfg)
-    seg = segment_ids_from_offsets(offsets, T)
+        bk = resolve_branch_backends(cfg)
+        seg = segment_ids_from_offsets(offsets, T)
 
-    # ball branch — block-diagonal by construction (offsets ∈ ball multiples)
-    out_ball = get_varlen(bk["ball"], "ball")(
-        q, k, v, offsets, mask, ball_size=cfg.ball_size, chunk_tokens=ct)
+        # ball branch — block-diagonal by construction (offsets ∈ ball multiples)
+        with jax.named_scope("ball"):
+            out_ball = get_varlen(bk["ball"], "ball")(
+                q, k, v, offsets, mask, ball_size=cfg.ball_size, chunk_tokens=ct)
 
-    # compression branch — packed tokens vs packed φ-blocks; block offsets
-    # are exact because sample boundaries are ball (hence ℓ) multiples
-    k_cmp = phi_apply(params["phi_k"], k[None], maskb, cfg)[0]     # (NB,Hkv,D)
-    v_cmp = phi_apply(params["phi_v"], v[None], maskb, cfg)[0]
-    blk_valid = block_validity(maskb, 1, T, ell)                   # (1,NB)
-    k_off = offsets // ell
-    flash_vl = get_varlen(bk["cmp"], "flash")
-    if cfg.group_compression:
-        q_cmp = phi_apply(params["phi_q"], q[None], maskb, cfg)[0]
-        out_c = flash_vl(q_cmp, k_cmp, v_cmp, k_off, k_off,
-                         key_valid=blk_valid[0], chunk_tokens=ct)  # (NB,Hq,D)
-        out_cmp = jnp.broadcast_to(out_c[:, None],
-                                   (nb, ell, Hq, D)).reshape(T, Hq, D)
-    else:
-        out_cmp = flash_vl(q, k_cmp, v_cmp, offsets, k_off,
-                           key_valid=blk_valid[0], chunk_tokens=ct)
+        # compression branch — packed tokens vs packed φ-blocks; block offsets
+        # are exact because sample boundaries are ball (hence ℓ) multiples
+        with jax.named_scope("compression"):
+            k_cmp = phi_apply(params["phi_k"], k[None], maskb, cfg)[0]  # (NB,Hkv,D)
+            v_cmp = phi_apply(params["phi_v"], v[None], maskb, cfg)[0]
+            blk_valid = block_validity(maskb, 1, T, ell)               # (1,NB)
+            k_off = offsets // ell
+            flash_vl = get_varlen(bk["cmp"], "flash")
+            if cfg.group_compression:
+                q_cmp = phi_apply(params["phi_q"], q[None], maskb, cfg)[0]
+                out_c = flash_vl(q_cmp, k_cmp, v_cmp, k_off, k_off,
+                                 key_valid=blk_valid[0], chunk_tokens=ct)  # (NB,Hq,D)
+                out_cmp = jnp.broadcast_to(out_c[:, None],
+                                           (nb, ell, Hq, D)).reshape(T, Hq, D)
+            else:
+                out_cmp = flash_vl(q, k_cmp, v_cmp, offsets, k_off,
+                                   key_valid=blk_valid[0], chunk_tokens=ct)
 
-    # selection branch — scores get segment isolation on top of the usual
-    # validity/own-ball masking, then the gather-attend is layout-agnostic
-    scores = _selection_scores(params, q[None], k_cmp[None], blk_valid,
-                               maskb, cfg, q_seg=seg)              # (1,G,Hkv,NB)
-    G = scores.shape[1]
-    top_idx, sel_valid, stats = _select_blocks(
-        scores, min(cfg.top_k, nb), None if select is None else select[None])
-    out_slc = get_varlen(bk["slc"], "selection")(
-        q, k, v, top_idx[0], sel_valid[0], offsets, mask,
-        block_size=ell, group_size=T // G, chunk_tokens=ct)
+        # selection branch — scores get segment isolation on top of the usual
+        # validity/own-ball masking, then the gather-attend is layout-agnostic
+        with jax.named_scope("selection"):
+            with jax.named_scope("score"):
+                scores = _selection_scores(params, q[None], k_cmp[None],
+                                           blk_valid, maskb, cfg,
+                                           q_seg=seg)                  # (1,G,Hkv,NB)
+            G = scores.shape[1]
+            with jax.named_scope("topk"):
+                top_idx, sel_valid, stats = _select_blocks(
+                    scores, min(cfg.top_k, nb),
+                    None if select is None else select[None])
+            with jax.named_scope("attend"):
+                out_slc = get_varlen(bk["slc"], "selection")(
+                    q, k, v, top_idx[0], sel_valid[0], offsets, mask,
+                    block_size=ell, group_size=T // G, chunk_tokens=ct)
 
-    gates = gate_values(params["gates"], cfg,
-                        None if x is None else x[None], Hq)
-    out = get_combine(bk["ball"])(
-        (out_ball[None], out_cmp[None], out_slc[None]),
-        (gates["ball"], gates["cmp"], gates["slc"]), maskb)[0].astype(in_dtype)
-    if return_aux:
-        return out, {"ball": out_ball, "cmp": out_cmp, "slc": out_slc,
-                     "indices": jnp.where(sel_valid, top_idx, -1)[0],
-                     "gates": gates, **stats}
-    return out
+        with jax.named_scope("combine"):
+            gates = gate_values(params["gates"], cfg,
+                                None if x is None else x[None], Hq)
+            out = get_combine(bk["ball"])(
+                (out_ball[None], out_cmp[None], out_slc[None]),
+                (gates["ball"], gates["cmp"], gates["slc"]),
+                maskb)[0].astype(in_dtype)
+        if return_aux:
+            return out, {"ball": out_ball, "cmp": out_cmp, "slc": out_slc,
+                         "indices": jnp.where(sel_valid, top_idx, -1)[0],
+                         "gates": gates, **stats}
+        return out

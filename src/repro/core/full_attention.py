@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.backend import resolve_backend
@@ -20,4 +21,5 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     None resolves via the usual precedence chain (default "auto").
     """
     bk = resolve_backend(backend)
-    return bk.flash(q, k, v, key_valid=mask, causal=causal)
+    with jax.named_scope("attention"):
+        return bk.flash(q, k, v, key_valid=mask, causal=causal)

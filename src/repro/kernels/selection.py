@@ -268,14 +268,14 @@ def _scatter_blocks(d_sel, idx, nb: int):
     are routed to block 0 with zero contribution anyway.
     """
     B, Hkv, G, k_star, ell, D = d_sel.shape
-    flat = d_sel.reshape(B, Hkv, G * k_star, ell, D)
-    tgt = jnp.maximum(idx.reshape(B, Hkv, G * k_star), 0)
-
-    def scat(buf, i, d):
-        return buf.at[i].add(d)
-
-    zeros = jnp.zeros((B, Hkv, nb, ell, D), d_sel.dtype)
-    return jax.vmap(jax.vmap(scat))(zeros, tgt, flat)
+    # one scatter over the flattened (B, Hkv, NB) block axis, ids offset by
+    # their (b, h) slot: the compiler flattens a vmapped scatter into a new
+    # instruction that loses its op_name, and with it its profiler scope
+    slot = jnp.arange(B * Hkv, dtype=idx.dtype).reshape(B, Hkv, 1) * nb
+    tgt = (jnp.maximum(idx.reshape(B, Hkv, G * k_star), 0) + slot).reshape(-1)
+    zeros = jnp.zeros((B * Hkv * nb, ell, D), d_sel.dtype)
+    out = zeros.at[tgt].add(d_sel.reshape(-1, ell, D))
+    return out.reshape(B, Hkv, nb, ell, D)
 
 
 @functools.lru_cache(maxsize=None)

@@ -34,11 +34,14 @@ def make_train_step(api, *, base_lr=1e-3, weight_decay=0.01, total_steps=100_000
         with _scope():
             (loss, metrics), grads = jax.value_and_grad(
                 api.loss, has_aux=True)(params, batch)
-        grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
-        lr = cosine_schedule(opt_state["step"], base_lr=base_lr,
-                             total_steps=total_steps, warmup_steps=warmup_steps)
-        params, opt_state = adamw_update(params, grads, opt_state, lr=lr,
-                                         weight_decay=weight_decay)
+        with jax.named_scope("clip"):
+            grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
+        with jax.named_scope("optimizer"):
+            lr = cosine_schedule(opt_state["step"], base_lr=base_lr,
+                                 total_steps=total_steps,
+                                 warmup_steps=warmup_steps)
+            params, opt_state = adamw_update(params, grads, opt_state, lr=lr,
+                                             weight_decay=weight_decay)
         out = {"loss": loss, "grad_norm": gnorm, "lr": lr}
         out.update({k: v for k, v in metrics.items() if v.ndim == 0})
         return params, opt_state, out

@@ -79,7 +79,8 @@ def attention_layer_apply(p, x, *, mcfg, causal: bool, mask=None,
     (``core.bsa._select_blocks``).
     """
     B, N, _ = x.shape
-    q, k, v = _project(p, x, mcfg, positions, rope)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _project(p, x, mcfg, positions, rope)
     want_sel = return_selection or select is not None
     if want_sel and (mcfg.attention != "bsa" or causal):
         raise NotImplementedError(
@@ -119,10 +120,12 @@ def attention_layer_apply(p, x, *, mcfg, causal: bool, mask=None,
         out = full_attention(q, k, v, mask=mask, causal=causal,
                              backend=mcfg.bsa.backend)
     out = out.reshape(B, N, mcfg.n_heads * mcfg.resolved_head_dim)
+    with jax.named_scope("attn_proj"):
+        out = dense(p["wo"], out)
     if want_sel:
-        return dense(p["wo"], out), {key: aux[key] for key in
-                                     ("indices", "gap", "flips") if key in aux}
-    return dense(p["wo"], out)
+        return out, {key: aux[key] for key in ("indices", "gap", "flips")
+                     if key in aux}
+    return out
 
 
 def cross_attention_apply(p, x, memory_kv, *, mcfg, mem_mask=None):

@@ -56,20 +56,24 @@ def pc_apply(params, feats, *, mcfg, mask=None, erwin_level_of=None,
     runs can be compared without a near-tie in top-k breaking differently —
     and adds per-layer ``gap``/``flips`` (``core.bsa._select_blocks``)."""
     cdt = mcfg.cdtype()
-    x = dense(params["embed"], feats.astype(cdt))
+    with jax.named_scope("embed"):
+        x = dense(params["embed"], feats.astype(cdt))
     x = constrain(x, "batch", "seq_res", "d_model")
     want_sel = return_selection or select is not None
 
     def layer(lp, x, level, sel=None):
-        h = rmsnorm(lp["norm1"], x, mcfg.norm_eps)
+        with jax.named_scope("norm"):
+            h = rmsnorm(lp["norm1"], x, mcfg.norm_eps)
         h = attention_layer_apply(lp["attn"], h, mcfg=mcfg, causal=False,
                                   mask=mask, positions=None, rope=False,
                                   erwin_level=level, offsets=offsets,
                                   select=sel, return_selection=want_sel)
         h, sel = h if want_sel else (h, None)
         x = x + h
-        h = rmsnorm(lp["norm2"], x, mcfg.norm_eps)
-        x = x + swiglu(lp["ffn"], h)
+        with jax.named_scope("norm"):
+            h = rmsnorm(lp["norm2"], x, mcfg.norm_eps)
+        with jax.named_scope("ffn"):
+            x = x + swiglu(lp["ffn"], h)
         return constrain(x, "batch", "seq_res", "d_model"), sel
 
     if mcfg.attention == "erwin" and erwin_level_of is None:
@@ -92,8 +96,10 @@ def pc_apply(params, feats, *, mcfg, mask=None, erwin_level_of=None,
             return fn(lp, x, sel=sel)
         x, sels = jax.lax.scan(body, x, (params["layers"], select))
 
-    x = rmsnorm(params["final_norm"], x, mcfg.norm_eps)
-    out = dense(params["head"], x).astype(jnp.float32)
+    with jax.named_scope("norm"):
+        x = rmsnorm(params["final_norm"], x, mcfg.norm_eps)
+    with jax.named_scope("head"):
+        out = dense(params["head"], x).astype(jnp.float32)
     return (out, sels) if want_sel else out
 
 
@@ -102,12 +108,13 @@ def pc_loss(params, batch, *, mcfg):
     An optional ``offsets`` key selects the packed-varlen layout."""
     pred = pc_apply(params, batch["feats"], mcfg=mcfg, mask=batch.get("mask"),
                     offsets=batch.get("offsets"))
-    err = (pred - batch["target"].astype(jnp.float32)) ** 2
-    m = batch.get("mask")
-    if m is not None:
-        err = jnp.where(m[..., None], err, 0.0)
-        denom = jnp.maximum(m.sum() * mcfg.out_dim, 1)
-    else:
-        denom = err.size
-    loss = err.sum() / denom
+    with jax.named_scope("loss"):
+        err = (pred - batch["target"].astype(jnp.float32)) ** 2
+        m = batch.get("mask")
+        if m is not None:
+            err = jnp.where(m[..., None], err, 0.0)
+            denom = jnp.maximum(m.sum() * mcfg.out_dim, 1)
+        else:
+            denom = err.size
+        loss = err.sum() / denom
     return loss, {"mse": loss}

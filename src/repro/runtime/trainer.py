@@ -14,6 +14,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import CheckpointManager, latest_step
 from repro.distributed.params import batch_shardings, opt_shardings, param_shardings
@@ -128,35 +129,46 @@ class Trainer:
         it = iter(batches)
         t_train0 = time.time()
         for step in range(step0, step0 + steps):
-            batch = next(it)
-            state = batch.pop("_state", None)
-            if self.mesh is not None:
-                b_sh = batch_shardings(
-                    jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                                 batch), self.mesh)
-                batch = jax.device_put(batch, b_sh)
-            t0 = time.time()
-            with self._ctx():
-                params, opt_state, metrics = self._jit_step(params, opt_state, batch)
-            jax.block_until_ready(metrics["loss"])
-            dt = time.time() - t0
-            self.watchdog.step(step, dt)
+            # each step is a profiler step (the step-time view reads it);
+            # its children split the host's part from the device's
+            with StepTraceAnnotation("repro.trainer.step", step_num=step):
+                with TraceAnnotation("repro.trainer.batch"):
+                    batch = next(it)
+                    state = batch.pop("_state", None)
+                    if self.mesh is not None:
+                        b_sh = batch_shardings(
+                            jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                                x.shape, x.dtype), batch), self.mesh)
+                        batch = jax.device_put(batch, b_sh)
+                t0 = time.time()
+                with TraceAnnotation("repro.trainer.dispatch"), self._ctx():
+                    params, opt_state, metrics = self._jit_step(
+                        params, opt_state, batch)
+                with TraceAnnotation("repro.trainer.wait"):
+                    jax.block_until_ready(metrics["loss"])
+                dt = time.time() - t0
+                self.watchdog.step(step, dt)
 
-            if step % self.cfg.log_every == 0 or step == step0 + steps - 1:
-                m = {k: float(v) for k, v in metrics.items()}
-                m.update(step=step, step_time_s=round(dt, 4))
-                self.metrics_history.append(m)
-                print(f"step {step:6d}  loss {m['loss']:.4f}  "
-                      f"gnorm {m.get('grad_norm', 0):.2f}  {dt*1e3:.0f} ms",
-                      flush=True)
-            if self.ckpt and (step % self.cfg.ckpt_every == 0 or self._preempted
-                              or step == step0 + steps - 1) and step > step0:
-                self.ckpt.save(step, {"params": params, "opt": opt_state},
-                               extra={"data_state": state} if state else None,
-                               block=self._preempted)
-                if self._preempted:
-                    print(f"preempted: state saved at step {step}", flush=True)
-                    break
+                if step % self.cfg.log_every == 0 or step == step0 + steps - 1:
+                    with TraceAnnotation("repro.trainer.log"):
+                        m = {k: float(v) for k, v in metrics.items()}
+                        m.update(step=step, step_time_s=round(dt, 4))
+                        self.metrics_history.append(m)
+                        print(f"step {step:6d}  loss {m['loss']:.4f}  "
+                              f"gnorm {m.get('grad_norm', 0):.2f}  "
+                              f"{dt*1e3:.0f} ms", flush=True)
+                if self.ckpt and (step % self.cfg.ckpt_every == 0
+                                  or self._preempted
+                                  or step == step0 + steps - 1) and step > step0:
+                    with TraceAnnotation("repro.trainer.checkpoint"):
+                        self.ckpt.save(
+                            step, {"params": params, "opt": opt_state},
+                            extra={"data_state": state} if state else None,
+                            block=self._preempted)
+                    if self._preempted:
+                        print(f"preempted: state saved at step {step}",
+                              flush=True)
+                        break
         self.watchdog.stop()
         if self.ckpt:
             self.ckpt.wait()

@@ -39,6 +39,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.backend import use_backend
 from repro.core.balltree import (bucket_length, pack_ragged, pack_varlen,
@@ -497,7 +498,6 @@ class GeometryEngine:
                          if api.forward_selection else None)
         self.clouds_served = 0
         self.points_served = 0
-        self.predict_time = 0.0
 
     def predict(self, clouds, *, select=None, return_selection: bool = False):
         """clouds: sequence of ``(points (n_i, d), feats (n_i, in_dim))``
@@ -520,23 +520,59 @@ class GeometryEngine:
                 f"{self.api.mcfg.attention!r} attention selects no blocks")
         results: list[np.ndarray] = []
         selections: list[dict] = []
-        t0 = time.time()
-        for s in range(0, len(clouds), self.batch_slots):
-            preds, sels = self._predict_batch(
-                clouds[s:s + self.batch_slots], want_sel,
-                None if select is None else select[s:s + self.batch_slots])
-            results.extend(preds)
-            selections.extend(sels)
-        self.predict_time += time.time() - t0
+        n_points = sum(len(p) for p, _ in clouds)
+        with TraceAnnotation("repro.engine.predict", clouds=len(clouds),
+                             points=n_points):
+            for s in range(0, len(clouds), self.batch_slots):
+                preds, sels = self._predict_batch(
+                    clouds[s:s + self.batch_slots], want_sel,
+                    None if select is None else select[s:s + self.batch_slots])
+                results.extend(preds)
+                selections.extend(sels)
         self.clouds_served += len(clouds)
-        self.points_served += sum(int(np.asarray(p).shape[0]) for p, _ in clouds)
+        self.points_served += n_points
         return (results, selections) if want_sel else results
 
     def _predict_batch(self, chunk, want_sel=False, select=None):
-        pts_list = [np.asarray(p) for p, _ in chunk]
-        fts_list = [np.asarray(f, np.float32) for _, f in chunk]
-        perms = build_balltree_permutations(pts_list, self.ball_size)
-        ordered = [f[perm] for f, perm in zip(fts_list, perms)]
+        """One engine batch, under the host spans ``repro.engine.batch`` >
+        ``balltree``, ``pack``, ``forward`` (the jitted call up to its
+        return: enqueue, or trace and compile), ``fetch`` (the wait for the
+        device and the copy) and ``unpack``; docs/architecture.md, Tracing."""
+        with TraceAnnotation("repro.engine.batch", clouds=len(chunk),
+                             points=sum(len(p) for p, _ in chunk),
+                             layout=self.layout) as span:
+            with TraceAnnotation("repro.engine.balltree"):
+                pts_list = [np.asarray(p) for p, _ in chunk]
+                fts_list = [np.asarray(f, np.float32) for _, f in chunk]
+                perms = build_balltree_permutations(pts_list, self.ball_size)
+                ordered = [f[perm] for f, perm in zip(fts_list, perms)]
+            with TraceAnnotation("repro.engine.pack"):
+                batch, places = self._pack(ordered, fts_list)
+                if select is not None:
+                    batch["select"] = jnp.asarray(self._replay_ids(
+                        select, places, *batch["mask"].shape))
+            span.set_metadata(length=batch["mask"].shape[1])
+            with TraceAnnotation("repro.engine.forward"), \
+                    _backend_scope(self.backend, self._mesh):
+                if want_sel:
+                    pred, sel = self._fwd_sel(self.params, batch)
+                else:
+                    pred = self._fwd(self.params, batch)
+            with TraceAnnotation("repro.engine.fetch"):
+                pred = np.asarray(pred)
+            with TraceAnnotation("repro.engine.unpack"):
+                out = []
+                for (row, start, _), f, perm in zip(places, fts_list, perms):
+                    unperm = np.empty((len(f),) + pred.shape[2:], pred.dtype)
+                    unperm[perm] = pred[row, start:start + len(f)]  # ball → caller order
+                    out.append(unperm)
+                return out, (self._cloud_ids(sel, places, batch["mask"].shape[1])
+                             if want_sel else [])
+
+    def _pack(self, ordered, fts_list):
+        """Ball-ordered features → the device batch in this engine's layout,
+        and the (batch row, first token, ball-padded length) of every
+        cloud."""
         if self.layout == "packed":
             feats, offsets, mask = pack_varlen(
                 ordered, self.ball_size, pad_to=self.pad_to,
@@ -544,41 +580,24 @@ class GeometryEngine:
             batch = {"feats": jnp.asarray(feats)[None],
                      "mask": jnp.asarray(mask)[None],
                      "offsets": jnp.asarray(offsets)}
-            # (batch row, first token, ball-padded length) of every cloud
-            places = [(0, int(a), int(b - a)) for a, b in
-                      zip(offsets[:len(chunk)], offsets[1:len(chunk) + 1])]
-        else:
-            target = self.pad_to or bucket_length(
-                max(f.shape[0] for f in ordered), self.ball_size)
-            # fully-masked dummy slots keep B static for the final short
-            # batch (every branch returns exact zeros for an all-invalid one)
-            pad_slots = self.batch_slots - len(chunk)
-            if pad_slots > 0:
-                ordered += [np.zeros((1, ordered[0].shape[1]),
-                                     np.float32)] * pad_slots
-            feats, mask = pack_ragged(ordered, self.ball_size, pad_to=target)
-            if pad_slots > 0:
-                mask[len(chunk):] = False
-            batch = {"feats": jnp.asarray(feats), "mask": jnp.asarray(mask)}
-            places = [(i, 0, bucket_length(len(f), self.ball_size,
-                                           geometric=False))
-                      for i, f in enumerate(fts_list)]
-        if select is not None:
-            batch["select"] = jnp.asarray(self._replay_ids(
-                select, places, *batch["mask"].shape))
-        with _backend_scope(self.backend, self._mesh):
-            if want_sel:
-                pred, sel = self._fwd_sel(self.params, batch)
-            else:
-                pred = self._fwd(self.params, batch)
-        pred = np.asarray(pred)
-        out = []
-        for (row, start, _), f, perm in zip(places, fts_list, perms):
-            unperm = np.empty((len(f),) + pred.shape[2:], pred.dtype)
-            unperm[perm] = pred[row, start:start + len(f)]  # ball → caller order
-            out.append(unperm)
-        return out, (self._cloud_ids(sel, places, batch["mask"].shape[1])
-                     if want_sel else [])
+            n = len(fts_list)
+            return batch, [(0, int(a), int(b - a))
+                           for a, b in zip(offsets[:n], offsets[1:n + 1])]
+        target = self.pad_to or bucket_length(
+            max(f.shape[0] for f in ordered), self.ball_size)
+        # fully-masked dummy slots keep B static for the final short
+        # batch (every branch returns exact zeros for an all-invalid one)
+        pad_slots = self.batch_slots - len(ordered)
+        if pad_slots > 0:
+            ordered = ordered + [np.zeros((1, ordered[0].shape[1]),
+                                          np.float32)] * pad_slots
+        feats, mask = pack_ragged(ordered, self.ball_size, pad_to=target)
+        if pad_slots > 0:
+            mask[len(fts_list):] = False
+        batch = {"feats": jnp.asarray(feats), "mask": jnp.asarray(mask)}
+        return batch, [(i, 0, bucket_length(len(f), self.ball_size,
+                                            geometric=False))
+                       for i, f in enumerate(fts_list)]
 
     def _replay_ids(self, select, places, n_rows, n_tokens):
         """Per-cloud ids (n_layers, G_i, Hkv, k*) → the batch's replay array
@@ -610,7 +629,3 @@ class GeometryEngine:
             out.append({"indices": np.where(ids >= 0, ids - start // ell, -1),
                         **stats})
         return out
-
-    @property
-    def points_per_second(self) -> float:
-        return self.points_served / max(self.predict_time, 1e-9)

@@ -155,3 +155,30 @@ def test_paged_gather_compiles_for_v5e(one_chip):
     got = _compiled_kernels(fn, [((4097 * 16, 2, 128), jnp.bfloat16),
                                  ((8, 64), jnp.int32)], one_chip)
     assert got == ["bsa_paged_gather"]
+
+
+def test_bsa_scopes_reach_the_v5e_kernels(one_chip, monkeypatch):
+    """``bsa_attention`` on the pallas backend, compiled for the chip: the
+    selection kernel's launch carries the ``bsa/selection/attend`` scope in
+    its ``op_name`` and keeps its kernel name (a trace reduction keys on
+    both)."""
+    from repro.core import bsa_attention, bsa_init
+    from repro.core.config import BSAConfig
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = BSAConfig(backend="pallas")                    # PAPER_BSA
+    params = jax.eval_shape(lambda: bsa_init(
+        jax.random.PRNGKey(0), cfg, n_heads=H, n_kv_heads=H, head_dim=D,
+        d_model=H * D))
+    spec = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = (jax.tree.map(lambda x: spec(x.shape, x.dtype), params),
+            *[spec((1, N, H, D))] * 3, spec((1, N), jnp.bool_))
+    fn = lambda p, q, k, v, m: bsa_attention(p, q, k, v, cfg=cfg, mask=m)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    launches = [line for line in hlo.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line]
+    selection = [line for line in launches
+                 if line.split("=")[0].strip().lstrip("%").startswith(
+                     "bsa_selection_fwd.")]
+    assert selection, [line.split("=")[0] for line in launches]
+    assert all("/bsa/selection/attend/" in line for line in selection)
