@@ -255,7 +255,7 @@ def selection_attention(q, k, v, top_idx, sel_valid, mask, *,
     defaults to ``mask`` under the classic N == L layout.  ``block_size``
     ℓ is the KV block length; ``group_size`` g = N/G tokens per query group.
     Returns (B, N, Hq, D).  Differentiable in q, k, v (dK/dV are
-    scatter-added back through the gathered indices)."""
+    summed back through the gathered indices, duplicates included)."""
     B, N, Hq, D = q.shape
     Hkv = k.shape[2]
     rep = Hq // Hkv

@@ -50,7 +50,7 @@ def selection_attention_ref(q, k, v, top_idx, sel_valid, mask, *,
     Hkv = k.shape[2]
     rep = Hq // Hkv
     ell = block_size
-    nb = N // ell
+    nb = k.shape[1] // ell                   # keys may outnumber queries
     G = top_idx.shape[1]
     g = N // G
     kb = k.reshape(B, nb, ell, Hkv, D)

@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, selection
+from selection_cases import SCHEDULES, SELECTION_CASES, selection_ids
 
 KEY = jax.random.PRNGKey(42)
 
@@ -86,26 +87,80 @@ def test_flash(B, N, L, H, D, mode, dtype):
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
+# shapes of the named selection cases (conftest.selection_ids)
+_SEL_CASE_SHAPES = {"shared": (2, 128, 2, 2, 32, 8, 8, 4),
+                    "gqa4": (1, 128, 8, 2, 32, 8, 8, 4),
+                    "chunked": (1, 128, 4, 2, 32, 8, 8, 4)}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,N,Hq,Hkv,D,ell,g,ks", [
-    (1, 256, 2, 1, 64, 8, 8, 4),
-    (2, 512, 4, 2, 64, 8, 16, 4),
-    (1, 256, 4, 4, 32, 16, 16, 2),   # MHA, bigger blocks
-    (1, 128, 8, 2, 64, 8, 8, 6),     # high GQA rep
+@pytest.mark.parametrize("B,N,Hq,Hkv,D,ell,g,ks,case,schedule", [
+    pytest.param(1, 256, 2, 1, 64, 8, 8, 4, "random", None, id="1-256-2-1-64-8-8-4"),
+    pytest.param(2, 512, 4, 2, 64, 8, 16, 4, "random", None, id="2-512-4-2-64-8-16-4"),
+    # MHA, bigger blocks
+    pytest.param(1, 256, 4, 4, 32, 16, 16, 2, "random", None, id="1-256-4-4-32-16-16-2"),
+    # high GQA rep
+    pytest.param(1, 128, 8, 2, 64, 8, 8, 6, "random", None, id="1-128-8-2-64-8-8-6"),
+] + [
+    pytest.param(*_SEL_CASE_SHAPES.get(case, (1, 128, 2, 1, 32, 8, 8, 4)), case,
+                 schedule, id=f"{case}-{schedule}")
+    for case in SELECTION_CASES for schedule in SCHEDULES
 ])
-def test_selection(B, N, Hq, Hkv, D, ell, g, ks, dtype):
-    k1, k2, k3, k4, k5 = jax.random.split(KEY, 5)
+def test_selection(B, N, Hq, Hkv, D, ell, g, ks, case, schedule, dtype,
+                   use_selection_schedule):
+    use_selection_schedule(schedule, 6 if case == "chunked" else None)
+    L = 2 * N if case == "wide-keys" else N        # a context shard's gathered keys
+    k1, k2, k3, k4 = jax.random.split(KEY, 4)
     q = _rand(k1, (B, N, Hq, D), dtype)
-    k = _rand(k2, (B, N, Hkv, D), dtype)
-    v = _rand(k3, (B, N, Hkv, D), dtype)
-    G, nb = N // g, N // ell
-    idx = jax.random.randint(k4, (B, G, Hkv, ks), 0, nb)
-    valid = jax.random.bernoulli(k5, 0.85, (B, G, Hkv, ks))
-    mask = jnp.ones((B, N), bool).at[:, -N // 8:].set(False)
+    k = _rand(k2, (B, L, Hkv, D), dtype)
+    v = _rand(k3, (B, L, Hkv, D), dtype)
+    G, nb = N // g, L // ell
+    idx, valid = selection_ids(case, k4, (B, G, Hkv, ks), nb)
+    pad = 3 * L // 8 if case == "padding" else L // 8
+    mask = jnp.ones((B, L), bool).at[:, -pad:].set(False)
+    if schedule is not None:
+        assert selection.selection_schedule(
+            "fwd", (B, Hkv, G, g * Hq // Hkv, D), (B, Hkv, nb, ell, D), ks,
+            dtype) == schedule
     out = ops.selection_attention(q, k, v, idx, valid, mask, block_size=ell, group_size=g)
     want = ref.selection_attention_ref(q, k, v, idx, valid, mask, block_size=ell, group_size=g)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("cell,batch,points,schedule", [
+    ("train", 8, 3840, "resident"),
+    ("serve-single", 1, 3840, "resident"),
+    ("serve-batch", 1, 8 * 3840, "resident"),     # 8 clouds on one packed axis
+    ("large", 1, 65536, "blocked"),
+])
+def test_selection_schedule_follows_shape(cell, batch, points, schedule,
+                                          use_selection_schedule, monkeypatch):
+    """The shapenet-bsa cells' selection launches (8 KV heads x 32, ℓ 8,
+    top-k 4, group 8, fp32) pick their schedule from shape and dtype alone,
+    and the launch runs under that schedule's scope."""
+    H, D, ell, k_star, g = 8, 32, 8, 4, 8
+    q = (batch, H, points // g, g, D)
+    kb = (batch, H, points // ell, ell, D)
+    for direction in ("fwd", "bwd"):
+        assert selection.selection_schedule(
+            direction, q, kb, k_star, jnp.float32) == schedule
+    if cell == "large":       # the budget decides: raised, the slab fits
+        monkeypatch.setattr(selection, "_RESIDENT_VMEM_BYTES", 512 << 20)
+        assert selection.selection_schedule(
+            "bwd", q, kb, k_star, jnp.float32) == "resident"
+        monkeypatch.undo()
+
+    use_selection_schedule(schedule)
+    n = 64
+    x = jnp.ones((1, n, 2, D))
+    idx = jnp.zeros((1, n // g, 2, k_star), jnp.int32)
+    f = lambda q, k, v: jnp.sum(ops.selection_attention(
+        q, k, v, idx, idx >= 0, None, block_size=ell, group_size=g))
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(x, x, x).as_text(
+        debug_info=True)
+    for kernel in ("bsa_selection_fwd", "bsa_selection_bwd"):
+        assert f'"{schedule}/{kernel}/pallas_call"' in text, kernel
 
 
 def test_selection_all_invalid_group_is_zero():

@@ -17,7 +17,8 @@ import pytest
 from repro.core import (BSAConfig, bsa_attention, bsa_init,
                         nsa_causal_attention, nsa_init)
 from repro.core.branches import repeat_kv
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, selection
+from selection_cases import SCHEDULES, SELECTION_CASES, selection_ids
 
 KEY = jax.random.PRNGKey(123)
 TOL = dict(atol=1e-3, rtol=1e-3)
@@ -120,16 +121,34 @@ def test_flash_causal_grads(mode):
     _assert_grads_close(got, want)
 
 
-@pytest.mark.parametrize("masked", [False, True])
-@pytest.mark.parametrize("rep", [1, 4])
-def test_selection_grads(masked, rep):
-    B, N, Hkv, D, ell, g, ks = 1, 128, 2, 32, 8, 8, 4
-    q, k, v, w = _qkvw(B, N, Hkv * rep, Hkv, D)
-    G, nb = N // g, N // ell
-    k1, k2 = jax.random.split(jax.random.fold_in(KEY, rep))
-    idx = jax.random.randint(k1, (B, G, Hkv, ks), 0, nb)
-    valid = jax.random.bernoulli(k2, 0.85, (B, G, Hkv, ks))
-    mask = _mask(B, N, masked)
+@pytest.mark.parametrize("rep,masked,case,schedule", [
+    pytest.param(rep, masked, "random", None, id=f"{rep}-{masked}")
+    for rep in (1, 4) for masked in (False, True)
+] + [
+    # no padding in "chunked": the partial last chunk's last group stays live
+    pytest.param(4 if case == "gqa4" else 1, case != "chunked", case, schedule,
+                 id=f"{case}-{schedule}")
+    for case in SELECTION_CASES for schedule in SCHEDULES
+])
+def test_selection_grads(rep, masked, case, schedule, use_selection_schedule):
+    """dQ/dK/dV against the oracle's; the named cases cover both schedules:
+    one block summed over every group, duplicate ids, −1 and all-invalid
+    groups, heavy key padding, GQA rep 4, L > N keys, a partial last chunk."""
+    use_selection_schedule(schedule, 6 if case == "chunked" else None)
+    B = 2 if case == "shared" else 1
+    N, Hkv, D, ell, g, ks = 128, 2, 32, 8, 8, 4
+    L = 2 * N if case == "wide-keys" else N
+    q, k, v, w = _qkvw(B, N, Hkv * rep, Hkv, D, L)
+    G, nb = N // g, L // ell
+    idx, valid = selection_ids(case, jax.random.fold_in(KEY, rep),
+                               (B, G, Hkv, ks), nb)
+    mask = _mask(B, L, masked)
+    if case == "padding":
+        mask = mask.at[:, -3 * L // 8:].set(False)
+    if schedule is not None:
+        assert selection.selection_schedule(
+            "bwd", (B, Hkv, G, g * rep, D), (B, Hkv, nb, ell, D), ks,
+            q.dtype) == schedule
 
     def loss(fn):
         def f(q, k, v):

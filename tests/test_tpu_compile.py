@@ -150,6 +150,41 @@ def test_selection_splits_launches_to_fit_smem(one_chip):
     assert _compiled_kernels(fn, big, one_chip).count("bsa_selection_fwd") == 2
 
 
+# (batch, points, schedule) of the selection kernel's two schedules at the
+# shapenet-bsa widths: BSA training (batch 8) and serve-batch's packed axis
+# (8 clouds padded to 3840 points, one row) keep the (b, h) K/V slab in VMEM;
+# a 65,536-point cloud's slab overflows the budget and takes the blocked grid
+_SCHEDULES = {"train": (8, N, "resident"), "serve-batch": (1, 8 * N, "resident"),
+              "large": (1, 65536, "blocked")}
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("cell", list(_SCHEDULES))
+def test_selection_schedule_compiles_for_v5e(one_chip, cell, direction):
+    """Each selection launch compiles on the schedule its shape picks, under
+    that schedule's scope inside the kernel's own name."""
+    batch, n, schedule = _SCHEDULES[cell]
+    fn, _, n_diff, fwd_names, bwd_names = _cases()["selection"]
+    sel = (batch, n // GROUP, H, TOP_K)
+    shapes = [((batch, n, H, D), jnp.float32)] * 3 + [
+        (sel, jnp.int32), (sel, jnp.bool_), ((batch, n), jnp.bool_)]
+    want = set(fwd_names)
+    if direction == "bwd":
+        want |= set(bwd_names)
+        f = fn
+        fn = jax.grad(lambda *a: jnp.sum(f(*a) ** 2),
+                      argnums=tuple(range(n_diff)))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    launches = {line.split("=")[0].strip().lstrip("%"): line
+                for line in hlo.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line}
+    names = {name.rsplit(".", 1)[0] for name in launches}
+    assert want <= names, f"compiled program lacks {sorted(want - names)}"
+    assert all(f"/{schedule}/" in line for name, line in launches.items()
+               if name.startswith("bsa_selection_")), sorted(launches)
+
+
 def test_paged_gather_compiles_for_v5e(one_chip):
     fn = lambda pool, rows: ops.paged_gather(pool, rows, interpret=False)
     got = _compiled_kernels(fn, [((4097 * 16, 2, 128), jnp.bfloat16),
